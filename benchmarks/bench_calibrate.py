@@ -14,8 +14,9 @@ Five sections:
       budget: a latency-feasible decode-slot count must be *rejected*
       for exceeding HBM, with the reason reported;
   (e) kernel-calibrated speed modes — the Pallas-kernel backend sweeps
-      real kernels into ``backend="pallas-kernel"`` PerfDB records and a
-      kernels+speed_modes profile, then a KV-bound plan over
+      real kernels into ``backend="pallas-kernel"`` (``"pallas-interpret"``
+      on the CPU) PerfDB records and a kernels+speed_modes profile, then
+      a KV-bound plan over
       ``speed_modes=("fp16", "int8", "speculative")`` must recommend a
       *non-fp16* config on cost-per-goodput, re-verified by independent
       simulation.
@@ -36,6 +37,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from repro.analysis.memory_model import kv_bytes_per_token
 from repro.calibrate import plan_capacity, simulate_candidate
+from repro.calibrate.kernel_bench import backend_label
 from repro.configs import get_config
 from repro.core import (BenchmarkSession, CalibrationSpec, MemorySpec,
                         ModelRef, PerfDB, PlanSpec)
@@ -178,9 +180,10 @@ def kernel_speed_mode_plan(session, smoke, profile_dir, out):
     """Acceptance: kernel-calibrated profile + speed-mode planning.
 
     The Pallas-kernel backend must land ``backend="pallas-kernel"``
-    records in the PerfDB and a kernels+speed_modes profile; a KV-bound
-    plan over fp16/int8/speculative must then recommend a non-fp16
-    config on cost-per-goodput, and that recommendation must survive an
+    (``"pallas-interpret"`` on the CPU) records in the PerfDB and a
+    kernels+speed_modes profile; a KV-bound plan over
+    fp16/int8/speculative must then recommend a non-fp16 config on
+    cost-per-goodput, and that recommendation must survive an
     independent re-simulation."""
     spec = CalibrationSpec(
         job_id="cal-kernels", model=ModelRef(name="gemma2-2b"),
@@ -195,8 +198,9 @@ def kernel_speed_mode_plan(session, smoke, profile_dir, out):
     handle = session.submit(spec)
     _, us = timed(session.run)
     m = handle.result().metrics
-    krecs = session.db.query(kind="calibration", backend="pallas-kernel")
-    assert krecs, "no backend=pallas-kernel records landed in the PerfDB"
+    backend = backend_label()
+    krecs = session.db.query(kind="calibration", backend=backend)
+    assert krecs, f"no backend={backend} records landed in the PerfDB"
     profile = m["profile"]
     assert profile.get("kernels"), "profile carries no kernel fits"
     assert set(profile.get("speed_modes", {})) >= {"int8", "speculative"}

@@ -91,6 +91,8 @@ def main() -> None:
     parser.add_argument("--db", default=None,
                         help="PerfDB JSONL path to append records to")
     args = parser.parse_args()
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     if args.config:
         run_config(args)
     else:

@@ -7,7 +7,9 @@ PerfDB → fit → profile pipeline, so the planner's latency model is
 anchored to the hardware-shaped code the serving engine actually runs.
 
 Per grid point one ``kind="calibration"`` record is emitted carrying
-``backend="pallas-kernel"`` provenance plus the kernel name and dtype.
+the kernel name, dtype and ``backend`` provenance: ``"pallas-kernel"``
+where the kernels compile for a chip, ``"pallas-interpret"`` where they
+run in interpret mode on the CPU (see :func:`backend_label`).
 Timing target:
 
   * **CPU (this container)** — the pure-jnp references are wall-clocked
@@ -39,7 +41,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 from repro.calibrate.fit import fit_phase
 from repro.calibrate.profile import CalibrationProfile
 
-BACKEND = "pallas-kernel"
+KERNEL_BACKENDS = ("pallas-kernel", "pallas-interpret")
 
 #: allclose tolerance per dtype for the kernel-vs-reference check
 #: (matches tests/test_kernels.py)
@@ -205,6 +207,12 @@ def _verify(case: KernelCase, dtype: str, batch: int, seq: int,
     return err
 
 
+def backend_label() -> str:
+    """``backend`` provenance of this process's kernel records."""
+    from repro.kernels import ops
+    return "pallas-interpret" if ops.interpret_mode() else "pallas-kernel"
+
+
 def resolve_target(target: str = "auto") -> str:
     """Which implementation the sweep clocks: "kernel" | "reference"."""
     if target in ("kernel", "reference"):
@@ -226,7 +234,7 @@ def kernel_records(kernels: Optional[Sequence[str]] = None, *,
     Records look like the model-sweep calibration records (``phase``,
     ``batch``, ``tokens``, ``result.latency_s``) so the same fitter
     consumes them, plus ``kernel``, ``dtype`` and
-    ``backend="pallas-kernel"`` provenance.
+    ``backend`` provenance (:func:`backend_label`).
     """
     import jax
     from repro.serving.latency_model import MeasuredLatency
@@ -237,6 +245,7 @@ def kernel_records(kernels: Optional[Sequence[str]] = None, *,
     if unknown:
         raise KeyError(f"unknown kernels {unknown} (known: {sorted(reg)})")
     mode = resolve_target(target)
+    backend = backend_label()
     meta = dict(meta or {})
     records: List[Dict[str, Any]] = []
     for name in names:
@@ -261,7 +270,7 @@ def kernel_records(kernels: Optional[Sequence[str]] = None, *,
                     lat = clock.measure(*args)
                     rec = dict(meta, kind="calibration", phase=case.phase,
                                batch=int(b), tokens=int(s),
-                               kernel=name, dtype=dt, backend=BACKEND,
+                               kernel=name, dtype=dt, backend=backend,
                                result={"latency_s": float(lat),
                                        "mode": f"{mode}-"
                                                f"{jax.default_backend()}"})
@@ -282,9 +291,10 @@ def fit_kernel_records(records: Iterable[Dict[str, Any]]
     groups: Dict[tuple, List[tuple]] = {}
     errs: Dict[tuple, float] = {}
     for rec in records:
-        if rec.get("backend") != BACKEND:
+        if rec.get("backend") not in KERNEL_BACKENDS:
             continue
-        key = (rec["kernel"], rec.get("dtype", "float32"), rec["phase"])
+        key = (rec["kernel"], rec.get("dtype", "float32"), rec["phase"],
+               rec["backend"])
         res = rec.get("result", {})
         groups.setdefault(key, []).append(
             (float(rec["batch"]), float(rec["tokens"]),
@@ -293,12 +303,13 @@ def fit_kernel_records(records: Iterable[Dict[str, Any]]
             errs[key] = max(errs.get(key, 0.0),
                             float(res["max_err_vs_ref"]))
     fits: Dict[str, Dict[str, Any]] = {}
-    for (kernel, dtype, phase), pts in sorted(groups.items()):
+    for key, pts in sorted(groups.items()):
+        kernel, dtype, phase, backend = key
         fit = fit_phase(pts, phase)
         d = fit.to_dict()
-        d.update(phase=phase, backend=BACKEND, kernel=kernel, dtype=dtype)
-        if (kernel, dtype, phase) in errs:
-            d["max_err_vs_ref"] = errs[(kernel, dtype, phase)]
+        d.update(phase=phase, backend=backend, kernel=kernel, dtype=dtype)
+        if key in errs:
+            d["max_err_vs_ref"] = errs[key]
         fits[f"{kernel}/{dtype}"] = d
     return fits
 

@@ -5,7 +5,8 @@ PerfDB record per grid point under ``kind="calibration"``:
 
   * **measured mode** — §4.2.2 generated canonical models (fc / cnn /
     lstm / transformer) are built and jitted per grid point and
-    wall-clocked for real on CPU (``MeasuredLatency``).  Families with a
+    wall-clocked for real on the first device (``MeasuredLatency``;
+    records say ``measured-<platform>``).  Families with a
     sequence axis yield prefill points at every (batch, seq) plus
     per-step decode points at seq 1; fc/cnn have no autoregressive
     phase, so their forward cost becomes prompt-length-1 prefill points
@@ -67,10 +68,12 @@ def oracle_records(oracle, *, batches: Sequence[int], seqs: Sequence[int],
 def measured_records(spec: CalibrationSpec,
                      meta: Optional[Dict[str, Any]] = None
                      ) -> List[Dict[str, Any]]:
-    """Execute the generated model for real on CPU at every grid point."""
+    """Execute the generated model for real on the first device at every
+    grid point."""
     import jax
 
     from repro.core import generator as gen_lib
+    from repro.runtime import measured_mode
     from repro.serving.latency_model import MeasuredLatency
 
     model = spec.model
@@ -117,7 +120,8 @@ def measured_records(spec: CalibrationSpec,
         for i, (_, _, _, inputs) in enumerate(points):
             best[i] = min(best[i], clock.measure(params, *inputs))
 
-    return [_record(meta, phase, b, toks, lat, "measured-cpu")
+    mode = measured_mode()
+    return [_record(meta, phase, b, toks, lat, mode)
             for (phase, b, toks, _), lat in zip(points, best)]
 
 
@@ -162,6 +166,11 @@ def fit_calibration(spec: CalibrationSpec,
                                     hw=hw_lib.HARDWARE[spec.hardware],
                                     chips=spec.chips).cold_start()
     records = list(records)
+    if mode == "measured":
+        from repro.runtime import measured_mode
+        source = measured_mode()
+    else:
+        source = "oracle"
     # grid metadata comes from the records actually measured — measured
     # fc/cnn sweeps collapse the seq axis, so the spec's grid would lie
     grid = {
@@ -173,8 +182,8 @@ def fit_calibration(spec: CalibrationSpec,
     }
     return fit_records(
         records, model=spec.model.label, hardware=spec.hardware,
-        chips=spec.chips, source="measured-cpu" if mode == "measured"
-        else "oracle", holdout_fraction=spec.holdout_fraction,
+        chips=spec.chips, source=source,
+        holdout_fraction=spec.holdout_fraction,
         cold_start_s=cold_start_s, grid=grid)
 
 

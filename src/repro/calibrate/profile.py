@@ -56,7 +56,7 @@ class CalibrationProfile:
     model: str
     hardware: str
     chips: int
-    source: str                       # measured-cpu | oracle
+    source: str                       # measured-<platform> | oracle
     prefill: PhaseFit
     decode: PhaseFit
     cold_start_s: float = 2.0

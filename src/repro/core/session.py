@@ -18,7 +18,8 @@ The four benchmark stages per job are unchanged:
   1 Generate — resolve the model (registered arch or canonical generated
                model) + workload trace,
   2 Serve    — run the serving pipeline (simulator clocked by the roofline
-               latency oracle, or real CPU execution for generated models),
+               latency oracle, or real execution on the first device for
+               generated models),
   3 Collect  — per-stage latencies, utilization, energy/cost,
   4 Analyze  — aggregate into PerfDB; recommender/leaderboard read it.
 """
@@ -84,6 +85,7 @@ def run_stages(spec: AnyJobSpec) -> JobResult:
             family=spec.model.family, layers=spec.model.layers,
             width=spec.model.width, batch=spec.model.batch_hint)
         import jax
+        from repro.runtime import device_info, measured_mode
         params, apply_fn, inputs = gen_lib.build(gspec)
         jitted = jax.jit(apply_fn)
         measured = MeasuredLatency(jitted).measure(params, *inputs)
@@ -100,7 +102,8 @@ def run_stages(spec: AnyJobSpec) -> JobResult:
                 "bytes": bytes_moved,
                 "intensity": flops / max(bytes_moved, 1.0),
                 "attained_flops": flops / measured,
-                "mode": "measured-cpu",
+                "mode": measured_mode(),
+                "device_kind": device_info()["device_kind"],
             },
             benchmark_wall_s=time.time() - t0)
 

@@ -36,10 +36,10 @@ def _int8_mm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_scr):
 
     @pl.when(kk == nk - 1)
     def _final():
-        sx = sx_ref[...].astype(jnp.float32)      # (bm,)
-        sw = sw_ref[...].astype(jnp.float32)      # (bn,)
+        sx = sx_ref[...].astype(jnp.float32)      # (bm, 1)
+        sw = sw_ref[...].astype(jnp.float32)      # (1, bn)
         o_ref[...] = (acc_scr[...].astype(jnp.float32)
-                      * sx[:, None] * sw[None, :]).astype(o_ref.dtype)
+                      * sx * sw).astype(o_ref.dtype)
 
 
 def int8_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, sx: jnp.ndarray,
@@ -59,11 +59,12 @@ def int8_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, sx: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            # scales as a column and a row: 2-D blocks match XLA's tiling
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(x_q, w_q, sx, sw)
+    )(x_q, w_q, sx.reshape(M, 1), sw.reshape(1, N))

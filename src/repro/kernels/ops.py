@@ -1,14 +1,14 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode so
-every call is still validated end-to-end; on TPU they compile to Mosaic.
-``set_use_kernels(False)`` routes callers to the pure-jnp references —
-the serving engine flips this per benchmark-job spec ("software tier").
+On a TPU the kernels compile to Mosaic.  On the CPU backend (the test
+suite) they run in Pallas's ``interpret=True`` mode, so every call is
+still checked end to end; ``interpret_mode()`` says which one a call
+gets, and is decided from the backend when a wrapper is traced.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,21 +16,13 @@ import jax.numpy as jnp
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import int8_matmul as _i8
-from repro.kernels import ref
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import wkv6 as _wkv
 
-_INTERPRET = jax.default_backend() == "cpu"
-_USE_KERNELS = True
-
 
 def interpret_mode() -> bool:
-    return _INTERPRET
-
-
-def set_use_kernels(flag: bool) -> None:
-    global _USE_KERNELS
-    _USE_KERNELS = flag
+    """True where the kernels run interpreted: on the CPU backend."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -39,49 +31,34 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0,
                     block_q: int = _fa.DEFAULT_BLOCK_Q,
                     block_k: int = _fa.DEFAULT_BLOCK_K) -> jnp.ndarray:
-    if not _USE_KERNELS:
-        return ref.mha_reference(q, k, v, causal=causal, window=window,
-                                 softcap=softcap)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, block_q=block_q,
-                               block_k=block_k, interpret=_INTERPRET)
+                               block_k=block_k, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "block_k"))
 def decode_attention(q, k, v, lengths, *, softcap: float = 0.0,
                      block_k: int = _dec.DEFAULT_BLOCK_K) -> jnp.ndarray:
-    if not _USE_KERNELS:
-        return ref.decode_attention_reference(q, k, v, lengths)
     return _dec.decode_attention(q, k, v, lengths, softcap=softcap,
-                                 block_k=block_k, interpret=_INTERPRET)
+                                 block_k=block_k, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def wkv6(r, k, v, logw, u, state0,
          *, chunk: int = _wkv.DEFAULT_CHUNK) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if not _USE_KERNELS:
-        return ref.wkv6_reference(r, k, v, logw, u, state0)
     return _wkv.wkv6(r, k, v, logw, u, state0, chunk=chunk,
-                     interpret=_INTERPRET)
+                     interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_r"))
 def rglru_scan(a, b, s0, *, chunk: int = _rg.DEFAULT_CHUNK,
                block_r: int = _rg.DEFAULT_BLOCK_R):
-    if not _USE_KERNELS:
-        return ref.rglru_reference(a, b, s0)
     return _rg.rglru_scan(a, b, s0, chunk=chunk, block_r=block_r,
-                          interpret=_INTERPRET)
+                          interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def int8_matmul(x_q, w_q, sx, sw, *, bm: int = _i8.DEFAULT_BM,
                 bn: int = _i8.DEFAULT_BN, bk: int = _i8.DEFAULT_BK):
-    if not _USE_KERNELS:
-        return ref.int8_matmul_reference(x_q, w_q, sx, sw)
     return _i8.int8_matmul(x_q, w_q, sx, sw, bm=bm, bn=bn, bk=bk,
-                           interpret=_INTERPRET)
-
-
-def quantize_rowwise(x):
-    return ref.quantize_rowwise(x)
+                           interpret=interpret_mode())

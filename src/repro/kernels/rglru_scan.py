@@ -20,6 +20,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 128
 DEFAULT_BLOCK_R = 512
+SUBLANES = 8   # rows per aligned (8, br) tile read from / written to VMEM
 
 
 def _rglru_kernel(a_ref, b_ref, s0_ref, out_ref, last_ref, state_scr, *,
@@ -29,20 +30,21 @@ def _rglru_kernel(a_ref, b_ref, s0_ref, out_ref, last_ref, state_scr, *,
 
     @pl.when(c == 0)
     def _init():
-        state_scr[...] = s0_ref[0].astype(jnp.float32)
+        state_scr[...] = s0_ref[0].astype(jnp.float32)      # (1, br)
 
-    a = a_ref[0].astype(jnp.float32)      # (T, br)
-    b = b_ref[0].astype(jnp.float32)
+    def tile(i, s):
+        rows = pl.ds(pl.multiple_of(i * SUBLANES, SUBLANES), SUBLANES)
+        a = a_ref[0, rows, :].astype(jnp.float32)           # (8, br)
+        b = b_ref[0, rows, :].astype(jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        out = jnp.zeros_like(a)
+        for t in range(SUBLANES):
+            s = a[t:t + 1] * s + b[t:t + 1]
+            out = jnp.where(row == t, s, out)
+        out_ref[0, rows, :] = out.astype(out_ref.dtype)
+        return s
 
-    def step(t, carry):
-        s, outs = carry
-        s = a[t] * s + b[t]
-        outs = jax.lax.dynamic_update_index_in_dim(outs, s, t, 0)
-        return s, outs
-
-    s, outs = jax.lax.fori_loop(
-        0, chunk, step, (state_scr[...], jnp.zeros_like(a)))
-    out_ref[0] = outs.astype(out_ref.dtype)
+    s = jax.lax.fori_loop(0, chunk // SUBLANES, tile, state_scr[...])
     state_scr[...] = s
 
     @pl.when(c == nc - 1)
@@ -57,11 +59,12 @@ def rglru_scan(a: jnp.ndarray, b: jnp.ndarray, s0: jnp.ndarray, *,
     B, S, R = a.shape
     chunk = min(chunk, S)
     block_r = min(block_r, R)
-    assert S % chunk == 0 and R % block_r == 0
+    assert chunk % SUBLANES == 0 and S % chunk == 0 and R % block_r == 0
     grid = (B, R // block_r, S // chunk)
 
     seq_spec = pl.BlockSpec((1, chunk, block_r), lambda bi, ri, c: (bi, c, ri))
-    vec_spec = pl.BlockSpec((1, block_r), lambda bi, ri, c: (bi, ri))
+    # state rows are (B, 1, R) so a block's last two dims are (1, block_r)
+    vec_spec = pl.BlockSpec((1, 1, block_r), lambda bi, ri, c: (bi, 0, ri))
     out, last = pl.pallas_call(
         functools.partial(_rglru_kernel, chunk=chunk),
         grid=grid,
@@ -69,9 +72,9 @@ def rglru_scan(a: jnp.ndarray, b: jnp.ndarray, s0: jnp.ndarray, *,
         out_specs=[seq_spec, vec_spec],
         out_shape=[
             jax.ShapeDtypeStruct(a.shape, a.dtype),
-            jax.ShapeDtypeStruct(s0.shape, jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, R), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_r,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
         interpret=interpret,
-    )(a, b, s0)
-    return out, last
+    )(a, b, s0.reshape(B, 1, R))
+    return out, last.reshape(B, R)

@@ -30,7 +30,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as shd
@@ -186,8 +185,8 @@ def _moe_ep_local(params, x, *, E: int, k: int, capacity_factor: float,
     pspec = {"router": P(), "wi": P("model", None, None),
              "wg": P("model", None, None), "wo": P("model", None, None)}
     xspec = P(b_axes, None, None)
-    return shard_map(block, mesh=mesh, in_specs=(pspec, xspec),
-                     out_specs=xspec, check_rep=False)(params, x)
+    return jax.shard_map(block, mesh=mesh, in_specs=(pspec, xspec),
+                         out_specs=xspec, check_vma=False)(params, x)
 
 
 def moe_apply(params: Dict, x: jnp.ndarray, *, num_experts: int, k: int,
@@ -211,9 +210,9 @@ def moe_apply(params: Dict, x: jnp.ndarray, *, num_experts: int, k: int,
         elif kind == "local":
             # whole block local per (batch[, seq]) shard; weights replicated
             xspec = P(b_axes, seq_ax, None)
-            y = shard_map(core, mesh=mesh,
-                          in_specs=(P(), xspec), out_specs=xspec,
-                          check_rep=False)(params, x)
+            y = jax.shard_map(core, mesh=mesh,
+                              in_specs=(P(), xspec), out_specs=xspec,
+                              check_vma=False)(params, x)
         else:
             # dispatch local, expert matmuls under GSPMD (TP/EP rules)
             C = _capacity(S, E, k, capacity_factor)
@@ -223,13 +222,14 @@ def moe_apply(params: Dict, x: jnp.ndarray, *, num_experts: int, k: int,
             xk = jnp.where(keep[..., None], jnp.repeat(x, k, axis=1), 0)
             spec3, spec2 = P(b_axes, None, None), P(b_axes, None)
             spec4 = P(b_axes, None, None, None)
-            buf = shard_map(functools.partial(_scatter_local, E=E, C=C),
-                            mesh=mesh, in_specs=(spec3, spec2, spec2),
-                            out_specs=spec4, check_rep=False)(xk, eidx, pos)
+            scatter = functools.partial(_scatter_local, E=E, C=C)
+            buf = jax.shard_map(scatter, mesh=mesh,
+                                in_specs=(spec3, spec2, spec2),
+                                out_specs=spec4, check_vma=False)(xk, eidx, pos)
             out_buf = _expert_ffn(params, buf)
-            yk = shard_map(_gather_local, mesh=mesh,
-                           in_specs=(spec4, spec2, spec2), out_specs=spec3,
-                           check_rep=False)(out_buf, eidx, pos)
+            yk = jax.shard_map(_gather_local, mesh=mesh,
+                               in_specs=(spec4, spec2, spec2), out_specs=spec3,
+                               check_vma=False)(out_buf, eidx, pos)
             w = (topk_w.reshape(B, S * k) * keep).astype(x.dtype)
             y = (yk * w[..., None]).reshape(B, S, k, d).sum(axis=2)
 

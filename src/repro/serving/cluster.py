@@ -1058,7 +1058,7 @@ def simulate_cluster(workload: WorkloadSpec, policy: BatchPolicy,
             "kv_network": disagg.kv_network,
             "kv_bytes_per_token": kv_bpt,
             "migrated_requests": len(transfers),
-            "mean_kv_transfer_s": (sum(transfers) / len(transfers)
+            "mean_kv_transfer_s": (math.fsum(transfers) / len(transfers)
                                    if transfers else 0.0),
         }
     fleet_info = None
@@ -1116,7 +1116,7 @@ def simulate_cluster(workload: WorkloadSpec, policy: BatchPolicy,
             "max_model_len": resolved.max_model_len,
             "peak_blocks": max(p["peak_blocks"] for p in per),
             "peak_occupancy": max(p["peak_occupancy"] for p in per),
-            "mean_occupancy": (sum(p["mean_occupancy"] for p in per)
+            "mean_occupancy": (math.fsum(p["mean_occupancy"] for p in per)
                                / len(per)),
             "prefix_hit_tokens": hits,
             "prefix_hit_rate": hits / served_tokens if served_tokens

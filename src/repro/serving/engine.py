@@ -49,6 +49,29 @@ def make_decode_fn(model) -> Callable:
     return decode_step
 
 
+def cached_and_full_logits(model, params, tokens: jnp.ndarray,
+                           prompt_len: int, max_len: int):
+    """Next-token logits at positions ``prompt_len-1 .. S-1``, two ways.
+
+    ``cached``: prefill of the first ``prompt_len`` tokens, then one decode
+    step per remaining token through the cache.  ``full``: one
+    teacher-forced ``forward`` over all S tokens.  Both (B, S-prompt_len+1,
+    V); they agree up to rounding when the cache path is right.
+    """
+    B, S = tokens.shape
+    prefill = jax.jit(make_prefill_fn(model, max_len=max_len))
+    decode = jax.jit(make_decode_fn(model), donate_argnums=(1,))
+    forward = jax.jit(lambda p, t: model.forward(p, t)[0])
+    cache, logits = prefill(params, tokens[:, :prompt_len],
+                            jnp.full((B,), prompt_len, jnp.int32))
+    cached = [logits]
+    for t in range(prompt_len, S):
+        cache, logits = decode(params, cache, tokens[:, t])
+        cached.append(logits)
+    full = forward(params, tokens)[:, prompt_len - 1:]
+    return jnp.stack(cached, axis=1), full
+
+
 def greedy_sample(logits: jnp.ndarray) -> jnp.ndarray:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
