@@ -178,28 +178,31 @@ def attention_apply(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     When ``kv`` is given it is used as the key/value source (decode against a
     cache, or cross-attention); otherwise self-attention over ``x``.
     """
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    if kv is None:
-        k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-        k = rope(k, positions, rope_theta)
-        kv_pos_eff = positions
-    else:
-        k, v = kv
-        kv_pos_eff = kv_pos
-    q = rope(q, positions, rope_theta)
-    q = constrain_attn_q(q)
-    if kv is None and _flash_ok(positions, window, softcap, k_valid):
-        from repro.kernels import ops as kops
-        w_eff = 0 if (window or 0) >= (1 << 29) else int(window or 0)
-        out = kops.flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, window=w_eff,
-            softcap=float(softcap)).transpose(0, 2, 1, 3)
-    else:
-        out = attend(q, k, v, positions, kv_pos_eff, causal=causal,
-                     window=window, softcap=softcap, k_valid=k_valid)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+        if kv is None:
+            k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+            k = rope(k, positions, rope_theta)
+            kv_pos_eff = positions
+        else:
+            k, v = kv
+            kv_pos_eff = kv_pos
+        q = rope(q, positions, rope_theta)
+        q = constrain_attn_q(q)
+    with jax.named_scope("attn_core"):
+        if kv is None and _flash_ok(positions, window, softcap, k_valid):
+            from repro.kernels import ops as kops
+            w_eff = 0 if (window or 0) >= (1 << 29) else int(window or 0)
+            out = kops.flash_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), causal=causal, window=w_eff,
+                softcap=float(softcap)).transpose(0, 2, 1, 3)
+        else:
+            out = attend(q, k, v, positions, kv_pos_eff, causal=causal,
+                         window=window, softcap=softcap, k_valid=k_valid)
+    with jax.named_scope("attn_out"):
+        y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     if return_kv:
         return y, (k, v)
     return y

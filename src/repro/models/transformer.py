@@ -159,11 +159,12 @@ class DecoderLM:
     # ------------------------------------------------------------- embeddings
     def _embed(self, params, tokens, prefix_embeds=None):
         cfg = self.cfg
-        x = params["embed"][tokens].astype(cfg.activation_dtype)
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.activation_dtype)
-        if prefix_embeds is not None:
-            x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
-        return constrain_act(x, "batch", "seq", "act_embed")
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(cfg.activation_dtype)
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.activation_dtype)
+            if prefix_embeds is not None:
+                x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
+            return constrain_act(x, "batch", "seq", "act_embed")
 
     def _logits(self, params, x):
         cfg = self.cfg
@@ -176,23 +177,26 @@ class DecoderLM:
     # -------------------------------------------------------- full-seq blocks
     def _attn_block(self, p, x, positions, window, valid):
         cfg = self.cfg
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        with jax.named_scope("attn_qkv"):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         h = L.attention_apply(
             p["attn"], h, positions, rope_theta=cfg.rope_theta, causal=True,
             window=window, softcap=cfg.attn_logit_softcap,
             k_valid=valid)
-        return x + h
+        with jax.named_scope("attn_out"):
+            return x + h
 
     def _ffn_block(self, p, x):
         cfg = self.cfg
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if cfg.is_moe:
-            h, aux = moe_lib.moe_apply(
-                p["ffn"], h, num_experts=cfg.num_experts,
-                k=cfg.experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor, return_aux=True)
-            return x + h, aux
-        return x + L.mlp_apply(p["ffn"], h), jnp.float32(0.0)
+        with jax.named_scope("moe" if cfg.is_moe else "mlp"):
+            h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+            if cfg.is_moe:
+                h, aux = moe_lib.moe_apply(
+                    p["ffn"], h, num_experts=cfg.num_experts,
+                    k=cfg.experts_per_token,
+                    capacity_factor=cfg.moe_capacity_factor, return_aux=True)
+                return x + h, aux
+            return x + L.mlp_apply(p["ffn"], h), jnp.float32(0.0)
 
     def _layer_seq(self, kind, p, x, positions, window, valid, rec_state):
         """One layer over a full sequence. Returns (x, aux, new_rec_state)."""
@@ -205,17 +209,21 @@ class DecoderLM:
             return x, aux, rec_state
         if kind == RGLRU:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h, new_state = rglru_lib.rglru_block_seq(p["rec"], h, rec_state)
+            with jax.named_scope("rglru"):
+                h, new_state = rglru_lib.rglru_block_seq(p["rec"], h, rec_state)
             x = x + h
             x, aux = self._ffn_block(p, x)
             return x, aux, new_state
         if kind == RWKV6:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h, tm_state = rwkv_lib.time_mix_seq(p["tm_cm"], h, cfg.rwkv_head_dim,
-                                                rec_state["tm"])
+            with jax.named_scope("rwkv_time_mix"):
+                h, tm_state = rwkv_lib.time_mix_seq(
+                    p["tm_cm"], h, cfg.rwkv_head_dim, rec_state["tm"])
             x = x + h
             h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-            h2, cm_state = rwkv_lib.channel_mix_seq(p["tm_cm"], h2, rec_state["cm"])
+            with jax.named_scope("rwkv_channel_mix"):
+                h2, cm_state = rwkv_lib.channel_mix_seq(p["tm_cm"], h2,
+                                                        rec_state["cm"])
             return x + h2, jnp.float32(0.0), {"tm": tm_state, "cm": cm_state}
         raise ValueError(kind)
 
@@ -272,10 +280,11 @@ class DecoderLM:
         else:
             x, aux = self._forward_hybrid(params, x, positions, valid, remat)
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        if return_hidden:
-            return x, aux
-        return self._logits(params, x), aux
+        with jax.named_scope("logits"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            if return_hidden:
+                return x, aux
+            return self._logits(params, x), aux
 
     def _forward_hybrid(self, params, x, positions, valid, remat):
         cfg = self.cfg
@@ -381,13 +390,22 @@ class DecoderLM:
     def _attn_prefill(self, p, x, positions, window, valid, lc):
         """Self-attn over the prompt, writing into an (unrotated) cache."""
         cfg = self.cfg
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        with jax.named_scope("attn_qkv"):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         y, (k, v) = L.attention_apply(
             p["attn"], h, positions, rope_theta=cfg.rope_theta, causal=True,
             window=window, softcap=cfg.attn_logit_softcap, k_valid=valid,
             return_kv=True)
+        with jax.named_scope("kv_write"):
+            lc = self._write_prompt_kv(k, v, positions, valid, lc)
+        with jax.named_scope("attn_out"):
+            return x + y, lc
+
+    @staticmethod
+    def _write_prompt_kv(k, v, positions, valid, lc):
+        """The prompt's keys and values into the layer's cache."""
         W = lc["k"].shape[1]
-        S = x.shape[1]
+        B, S = positions.shape
         if W >= S:
             kc = lc["k"].at[:, :S].set(k.astype(lc["k"].dtype))
             vc = lc["v"].at[:, :S].set(v.astype(lc["v"].dtype))
@@ -400,7 +418,6 @@ class DecoderLM:
             # p ≡ s (mod W).  A gather (one winner per slot) avoids the
             # unordered-duplicate-scatter hazard:
             #   p(s) = len-1 − ((len-1−s) mod W)
-            B = x.shape[0]
             lens = (valid.sum(axis=1).astype(jnp.int32) if valid is not None
                     else jnp.full((B,), S, jnp.int32))
             s_idx = jnp.arange(W)[None, :]                       # (1, W)
@@ -411,30 +428,35 @@ class DecoderLM:
             kc = k[b, gidx].astype(lc["k"].dtype)
             vc = v[b, gidx].astype(lc["v"].dtype)
             slot_pos = jnp.where(ok, last, -1)
-        return x + y, {"k": kc, "v": vc, "slot_pos": slot_pos}
+        return {"k": kc, "v": vc, "slot_pos": slot_pos}
 
     def _attn_decode(self, p, x, q_pos, window, lc):
         """One-token attention against the cache; x: (B, 1, D)."""
         cfg = self.cfg
         B = x.shape[0]
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wq"])
-        k_new = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wk"])
-        v_new = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wv"])
-        q = L.rope(q, q_pos[:, None], cfg.rope_theta)
-        k_new = L.rope(k_new, q_pos[:, None], cfg.rope_theta)
-        W = lc["k"].shape[1]
-        slot = q_pos % W
-        b = jnp.arange(B)
-        kc = lc["k"].at[b, slot].set(k_new[:, 0].astype(lc["k"].dtype))
-        vc = lc["v"].at[b, slot].set(v_new[:, 0].astype(lc["v"].dtype))
-        slot_pos = lc["slot_pos"].at[b, slot].set(q_pos)
-        k_valid = slot_pos >= 0
-        out = L.attend(q, kc.astype(q.dtype), vc.astype(q.dtype),
-                       q_pos[:, None], slot_pos, causal=True, window=window,
-                       softcap=cfg.attn_logit_softcap, k_valid=k_valid)
-        y = jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"])
-        return x + y, {"k": kc, "v": vc, "slot_pos": slot_pos}
+        with jax.named_scope("attn_qkv"):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wq"])
+            k_new = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wk"])
+            v_new = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wv"])
+            q = L.rope(q, q_pos[:, None], cfg.rope_theta)
+            k_new = L.rope(k_new, q_pos[:, None], cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            W = lc["k"].shape[1]
+            slot = q_pos % W
+            b = jnp.arange(B)
+            kc = lc["k"].at[b, slot].set(k_new[:, 0].astype(lc["k"].dtype))
+            vc = lc["v"].at[b, slot].set(v_new[:, 0].astype(lc["v"].dtype))
+            slot_pos = lc["slot_pos"].at[b, slot].set(q_pos)
+        with jax.named_scope("attn_core"):
+            k_valid = slot_pos >= 0
+            out = L.attend(q, kc.astype(q.dtype), vc.astype(q.dtype),
+                           q_pos[:, None], slot_pos, causal=True,
+                           window=window, softcap=cfg.attn_logit_softcap,
+                           k_valid=k_valid)
+        with jax.named_scope("attn_out"):
+            y = jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"])
+            return x + y, {"k": kc, "v": vc, "slot_pos": slot_pos}
 
     # ---------------------------------------------------------------- prefill
     def _layer_prefill(self, kind, p, x, positions, window, valid, lc):
@@ -447,18 +469,21 @@ class DecoderLM:
             return x, lc
         if kind == RGLRU:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h, lc = rglru_lib.rglru_block_seq(p["rec"], h, lc, valid=valid)
+            with jax.named_scope("rglru"):
+                h, lc = rglru_lib.rglru_block_seq(p["rec"], h, lc, valid=valid)
             x = x + h
             x, _ = self._ffn_block(p, x)
             return x, lc
         if kind == RWKV6:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h, tm = rwkv_lib.time_mix_seq(p["tm_cm"], h, cfg.rwkv_head_dim,
-                                          lc["tm"], valid=valid)
+            with jax.named_scope("rwkv_time_mix"):
+                h, tm = rwkv_lib.time_mix_seq(p["tm_cm"], h, cfg.rwkv_head_dim,
+                                              lc["tm"], valid=valid)
             x = x + h
             h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-            h2, cm = rwkv_lib.channel_mix_seq(p["tm_cm"], h2, lc["cm"],
-                                              valid=valid)
+            with jax.named_scope("rwkv_channel_mix"):
+                h2, cm = rwkv_lib.channel_mix_seq(p["tm_cm"], h2, lc["cm"],
+                                                  valid=valid)
             return x + h2, {"tm": tm, "cm": cm}
         raise ValueError(kind)
 
@@ -507,8 +532,9 @@ class DecoderLM:
             new_cache = {"lengths": lengths, "periods": new_periods,
                          "tail": new_tail}
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return new_cache, _gather_last(self._logits(params, x), lengths)
+        with jax.named_scope("logits"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            return new_cache, _gather_last(self._logits(params, x), lengths)
 
     # ------------------------------------------------------------ decode step
     def _layer_decode(self, kind, p, x, q_pos, window, lc):
@@ -521,17 +547,21 @@ class DecoderLM:
             return x, lc
         if kind == RGLRU:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h1, lc = rglru_lib.rglru_block_step(p["rec"], h[:, 0], lc)
+            with jax.named_scope("rglru"):
+                h1, lc = rglru_lib.rglru_block_step(p["rec"], h[:, 0], lc)
             x = x + h1[:, None]
             x, _ = self._ffn_block(p, x)
             return x, lc
         if kind == RWKV6:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-            h1, tm = rwkv_lib.time_mix_step(p["tm_cm"], h[:, 0],
-                                            cfg.rwkv_head_dim, lc["tm"])
+            with jax.named_scope("rwkv_time_mix"):
+                h1, tm = rwkv_lib.time_mix_step(p["tm_cm"], h[:, 0],
+                                                cfg.rwkv_head_dim, lc["tm"])
             x = x + h1[:, None]
             h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-            h2s, cm = rwkv_lib.channel_mix_step(p["tm_cm"], h2[:, 0], lc["cm"])
+            with jax.named_scope("rwkv_channel_mix"):
+                h2s, cm = rwkv_lib.channel_mix_step(p["tm_cm"], h2[:, 0],
+                                                    lc["cm"])
             return x + h2s[:, None], {"tm": tm, "cm": cm}
         raise ValueError(kind)
 
@@ -575,5 +605,6 @@ class DecoderLM:
             new_cache = {"lengths": q_pos + 1, "periods": new_periods,
                          "tail": new_tail}
 
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return new_cache, self._logits(params, x[:, 0])
+        with jax.named_scope("logits"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            return new_cache, self._logits(params, x[:, 0])

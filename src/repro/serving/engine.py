@@ -73,7 +73,8 @@ def cached_and_full_logits(model, params, tokens: jnp.ndarray,
 
 
 def greedy_sample(logits: jnp.ndarray) -> jnp.ndarray:
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def make_generate_fn(model, steps: int) -> Callable:
