@@ -133,7 +133,16 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     logits = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     logits = _softcap(logits, softcap)
-    mask = jnp.ones((B, S, T), dtype=bool)
+    mask = _attn_mask(q_pos, k_pos, causal, window, k_valid)
+    logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
+    return out.reshape(B, S, H, hd).astype(q.dtype)
+
+
+def _attn_mask(q_pos, k_pos, causal: bool, window, k_valid) -> jnp.ndarray:
+    """(B, S, T) bool: which keys each query may see."""
+    mask = jnp.ones((q_pos.shape[0], q_pos.shape[1], k_pos.shape[1]), bool)
     if causal:
         mask &= k_pos[:, None, :] <= q_pos[:, :, None]
     # window may be a traced per-layer scalar (scan over mixed local/global
@@ -142,10 +151,42 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         mask &= k_pos[:, None, :] > (q_pos[:, :, None] - window)
     if k_valid is not None:
         mask &= k_valid[:, None, :]
-    logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    return mask
+
+
+def attend_cached(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                  k_new: jnp.ndarray, v_new: jnp.ndarray,
+                  q_pos: jnp.ndarray, k_pos: jnp.ndarray,
+                  *, window: int = 0, softcap: float = 0.0,
+                  k_valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """One query per row against a head-major cache plus the row's own new
+    key and value, which the cache does not hold yet.
+
+    q: (B, 1, H, hd); k/v: (B, K, T, hd); k_new/v_new: (B, K, hd) at
+    position q_pos: (B,); k_pos, k_valid: (B, T).  The caller masks out
+    of ``k_valid`` the slot the new entry will take.  One softmax over
+    the cache and the new entry, as ``attend`` computes it over the cache
+    with the new entry written in: causal, windowed and softcapped, in
+    float32.  Returns (B, 1, H, hd).
+    """
+    B, _, H, hd = q.shape
+    K = k.shape[1]
+    qg = q.reshape(B, K, H // K, hd).astype(jnp.float32)
+    scale = 1.0 / math.sqrt(hd)
+    logits = jnp.einsum("bkgd,bktd->bkgt", qg, k.astype(jnp.float32)) * scale
+    logits = _softcap(logits, softcap)
+    mask = _attn_mask(q_pos[:, None], k_pos, True, window, k_valid)
+    logits = jnp.where(mask[:, None], logits, -1e30)
+    # the new entry sits at the query's own position: always visible
+    fresh = _softcap(jnp.einsum("bkgd,bkd->bkg", qg,
+                                k_new.astype(jnp.float32)) * scale, softcap)
+    top = jnp.maximum(logits.max(axis=-1), fresh)
+    probs = jnp.exp(logits - top[..., None])
+    p_fresh = jnp.exp(fresh - top)
+    out = (jnp.einsum("bkgt,bktd->bkgd", probs, v.astype(jnp.float32))
+           + p_fresh[..., None] * v_new.astype(jnp.float32)[:, :, None])
+    out = out / (probs.sum(axis=-1) + p_fresh)[..., None]
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 # Attention implementation toggle: "xla" (pure jnp, default — what the

@@ -323,8 +323,8 @@ class DecoderLM:
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
             W = self._attn_cache_len(kind, max_len)
             return {
-                "k": jnp.zeros((batch, W, cfg.num_kv_heads, cfg.head_dim), dtype),
-                "v": jnp.zeros((batch, W, cfg.num_kv_heads, cfg.head_dim), dtype),
+                "k": jnp.zeros((batch, cfg.num_kv_heads, W, cfg.head_dim), dtype),
+                "v": jnp.zeros((batch, cfg.num_kv_heads, W, cfg.head_dim), dtype),
                 "slot_pos": jnp.full((batch, W), -1, jnp.int32),
             }
         if kind == RGLRU:
@@ -357,8 +357,8 @@ class DecoderLM:
 
     def _layer_cache_axes(self, kind: str):
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-            return {"k": ("batch", "kv", "kv_heads", "head_dim"),
-                    "v": ("batch", "kv", "kv_heads", "head_dim"),
+            return {"k": ("batch", "kv_heads", "kv", "head_dim"),
+                    "v": ("batch", "kv_heads", "kv", "head_dim"),
                     "slot_pos": ("batch", "kv")}
         if kind == RGLRU:
             return {"s": ("batch", "rnn"),
@@ -403,12 +403,14 @@ class DecoderLM:
 
     @staticmethod
     def _write_prompt_kv(k, v, positions, valid, lc):
-        """The prompt's keys and values into the layer's cache."""
-        W = lc["k"].shape[1]
+        """The prompt's keys and values, (B, S, K, hd), into the layer's
+        head-major cache, (B, K, W, hd)."""
+        W = lc["k"].shape[2]
         B, S = positions.shape
+        head_major = lambda a: a.transpose(0, 2, 1, 3).astype(lc["k"].dtype)
         if W >= S:
-            kc = lc["k"].at[:, :S].set(k.astype(lc["k"].dtype))
-            vc = lc["v"].at[:, :S].set(v.astype(lc["v"].dtype))
+            kc = lc["k"].at[:, :, :S].set(head_major(k))
+            vc = lc["v"].at[:, :, :S].set(head_major(v))
             pos = positions
             slot_pos = lc["slot_pos"].at[:, :S].set(
                 jnp.where(valid if valid is not None else jnp.ones_like(pos, bool),
@@ -425,15 +427,25 @@ class DecoderLM:
             ok = (last >= 0) & (lens[:, None] > 0)
             gidx = jnp.clip(last, 0, S - 1)
             b = jnp.arange(B)[:, None]
-            kc = k[b, gidx].astype(lc["k"].dtype)
-            vc = v[b, gidx].astype(lc["v"].dtype)
+            kc = head_major(k[b, gidx])
+            vc = head_major(v[b, gidx])
             slot_pos = jnp.where(ok, last, -1)
         return {"k": kc, "v": vc, "slot_pos": slot_pos}
 
-    def _attn_decode(self, p, x, q_pos, window, lc):
-        """One-token attention against the cache; x: (B, 1, D)."""
+    @staticmethod
+    def _decode_visible(slot_pos, q_pos):
+        """(B, W): the live slots a decode step attends to, less the slot
+        each row's new entry takes (in a ring buffer, the entry it
+        evicts)."""
+        W = slot_pos.shape[-1]
+        return (slot_pos >= 0) & (jnp.arange(W) != (q_pos % W)[:, None])
+
+    def _attn_decode(self, p, x, q_pos, window, kv):
+        """One-token attention against a layer's cache, which it only
+        reads; x: (B, 1, D).  ``kv``: "k"/"v" (B, K, W, hd); "slot_pos" and
+        "visible" (``_decode_visible``), (B, W).  Returns x and the new
+        (B, K, hd) key and value, for the caller to write."""
         cfg = self.cfg
-        B = x.shape[0]
         with jax.named_scope("attn_qkv"):
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wq"])
@@ -441,22 +453,38 @@ class DecoderLM:
             v_new = jnp.einsum("bsd,dhk->bshk", h, p["attn"]["wv"])
             q = L.rope(q, q_pos[:, None], cfg.rope_theta)
             k_new = L.rope(k_new, q_pos[:, None], cfg.rope_theta)
-        with jax.named_scope("kv_write"):
-            W = lc["k"].shape[1]
-            slot = q_pos % W
-            b = jnp.arange(B)
-            kc = lc["k"].at[b, slot].set(k_new[:, 0].astype(lc["k"].dtype))
-            vc = lc["v"].at[b, slot].set(v_new[:, 0].astype(lc["v"].dtype))
-            slot_pos = lc["slot_pos"].at[b, slot].set(q_pos)
+            k_new = k_new[:, 0].astype(kv["k"].dtype)
+            v_new = v_new[:, 0].astype(kv["v"].dtype)
         with jax.named_scope("attn_core"):
-            k_valid = slot_pos >= 0
-            out = L.attend(q, kc.astype(q.dtype), vc.astype(q.dtype),
-                           q_pos[:, None], slot_pos, causal=True,
-                           window=window, softcap=cfg.attn_logit_softcap,
-                           k_valid=k_valid)
+            out = L.attend_cached(q, kv["k"], kv["v"], k_new, v_new, q_pos,
+                                  kv["slot_pos"], window=window,
+                                  softcap=cfg.attn_logit_softcap,
+                                  k_valid=kv["visible"])
         with jax.named_scope("attn_out"):
             y = jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"])
-            return x + y, {"k": kc, "v": vc, "slot_pos": slot_pos}
+            return x + y, (k_new, v_new)
+
+    @staticmethod
+    def _write_decode_kv(lc, k_new, v_new, q_pos):
+        """Each row's new key, value and position into its slot of a cache
+        of one layer, (B, K, W, hd), or of the stack, (L, B, K, W, hd), with
+        new entries (B, K, hd) or (L, B, K, hd).  One dynamic_update_slice
+        per row and tensor, in place when the cache is donated: a scatter
+        here makes XLA re-lay the whole stack."""
+        lead = lc["k"].ndim - 4
+        zeros = (0,) * lead
+        slot = q_pos % lc["slot_pos"].shape[-1]
+        k, v, pos = lc["k"], lc["v"], lc["slot_pos"]
+        for b in range(q_pos.shape[0]):
+            at = zeros + (b, 0, slot[b], 0)
+            k = jax.lax.dynamic_update_slice(
+                k, k_new[..., b:b + 1, :, None, :], at)
+            v = jax.lax.dynamic_update_slice(
+                v, v_new[..., b:b + 1, :, None, :], at)
+            pos = jax.lax.dynamic_update_slice(
+                pos, jnp.full(pos.shape[:lead] + (1, 1), q_pos[b]),
+                zeros + (b, slot[b]))
+        return {"k": k, "v": v, "slot_pos": pos}
 
     # ---------------------------------------------------------------- prefill
     def _layer_prefill(self, kind, p, x, positions, window, valid, lc):
@@ -538,13 +566,15 @@ class DecoderLM:
 
     # ------------------------------------------------------------ decode step
     def _layer_decode(self, kind, p, x, q_pos, window, lc):
+        """One layer's decode step → (x, new state); an attention layer
+        only reads its cache and returns its new key and value instead."""
         cfg = self.cfg
         p = L.cast_layer_params(p, cfg.activation_dtype)
         x = constrain_act(x, "batch", "seq", "act_embed")
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-            x, lc = self._attn_decode(p, x, q_pos, window, lc)
+            x, kv_new = self._attn_decode(p, x, q_pos, window, lc)
             x, _ = self._ffn_block(p, x)
-            return x, lc
+            return x, kv_new
         if kind == RGLRU:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             with jax.named_scope("rglru"):
@@ -572,26 +602,53 @@ class DecoderLM:
         q_pos = cache["lengths"]
 
         if self.homogeneous:
-            windows = jnp.asarray(
-                [cfg.local_window if k == ATTN_LOCAL else GLOBAL_WINDOW
-                 for k in self.kinds], dtype=jnp.int32)
-            kind0 = self.kinds[0] if self.kinds[0] == RWKV6 else ATTN_GLOBAL
-            def body(x, xs):
-                p, w, lc = xs
-                x, lc = self._layer_decode(kind0, p, x, q_pos, w, lc)
-                return x, lc
-            x, new_layers = scan_layers(body, x,
-                                        (params["layers"], windows,
-                                         cache["layers"]), cfg.cost_unroll)
+            if self.kinds[0] == RWKV6:
+                # the recurrent state is rewritten whole in every layer
+                def body(x, xs):
+                    p, lc = xs
+                    return self._layer_decode(RWKV6, p, x, q_pos,
+                                              GLOBAL_WINDOW, lc)
+                x, new_layers = scan_layers(body, x,
+                                            (params["layers"],
+                                             cache["layers"]), cfg.cost_unroll)
+            else:
+                # the stacked cache is only read inside the scan; each
+                # layer's new key and value come out as the scan's ys and
+                # are written once, in place, after it
+                windows = jnp.asarray(
+                    [cfg.local_window if k == ATTN_LOCAL else GLOBAL_WINDOW
+                     for k in self.kinds], dtype=jnp.int32)
+                kv = cache["layers"]
+                slot_pos = kv["slot_pos"][0]    # the same in every layer
+                visible = self._decode_visible(slot_pos, q_pos)
+                def body(x, xs):
+                    p, w, k, v = xs
+                    lc = {"k": k, "v": v, "slot_pos": slot_pos,
+                          "visible": visible}
+                    return self._layer_decode(ATTN_GLOBAL, p, x, q_pos, w, lc)
+                x, (k_new, v_new) = scan_layers(
+                    body, x, (params["layers"], windows, kv["k"], kv["v"]),
+                    cfg.cost_unroll)
+                with jax.named_scope("kv_write"):
+                    new_layers = self._write_decode_kv(kv, k_new, v_new, q_pos)
             new_cache = {"lengths": q_pos + 1, "layers": new_layers}
         else:
+            def layer(kind, p, x, w, lc):
+                if kind not in (ATTN_GLOBAL, ATTN_LOCAL):
+                    return self._layer_decode(kind, p, x, q_pos, w, lc)
+                visible = self._decode_visible(lc["slot_pos"], q_pos)
+                x, out = self._layer_decode(kind, p, x, q_pos, w,
+                                            dict(lc, visible=visible))
+                with jax.named_scope("kv_write"):
+                    return x, self._write_decode_kv(lc, *out, q_pos)
+
             def body(x, xs):
                 p, lc = xs
                 new_lc = {}
                 for i, kind in enumerate(cfg.layer_pattern):
                     w = cfg.local_window if kind == ATTN_LOCAL else GLOBAL_WINDOW
-                    x, new_lc[f"l{i}"] = self._layer_decode(
-                        kind, p[f"l{i}"], x, q_pos, w, lc[f"l{i}"])
+                    x, new_lc[f"l{i}"] = layer(kind, p[f"l{i}"], x, w,
+                                               lc[f"l{i}"])
                 return x, new_lc
             x, new_periods = scan_layers(body, x,
                                          (params["periods"],
@@ -599,9 +656,8 @@ class DecoderLM:
             new_tail = {}
             for i, kind in enumerate(self.tail_kinds):
                 w = cfg.local_window if kind == ATTN_LOCAL else GLOBAL_WINDOW
-                x, new_tail[f"t{i}"] = self._layer_decode(
-                    kind, params["tail"][f"t{i}"], x, q_pos, w,
-                    cache["tail"][f"t{i}"])
+                x, new_tail[f"t{i}"] = layer(kind, params["tail"][f"t{i}"], x,
+                                             w, cache["tail"][f"t{i}"])
             new_cache = {"lengths": q_pos + 1, "periods": new_periods,
                          "tail": new_tail}
 

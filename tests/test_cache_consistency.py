@@ -20,7 +20,8 @@ from repro.serving.engine import cached_and_full_logits, serving_config
 ATOL = 1e-4
 
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "rwkv6-7b"])
+@pytest.mark.parametrize("arch", ["granite-3-2b", "rwkv6-7b", "gemma2-2b",
+                                  "recurrentgemma-9b"])
 def test_cached_decode_matches_forward(arch):
     cfg = serving_config(reduced(get_config(arch)))
     model = build_model(cfg)
