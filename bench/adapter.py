@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import spec, weights
 from bench.generator import Request
 from repro.configs import get_config
 from repro.models import ModelConfig, build_model
@@ -37,19 +37,16 @@ from repro.serving.engine import (greedy_sample, make_decode_fn,
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 span = jax.profiler.TraceAnnotation
-# a configuration file's keys and the catalog's names for them
-_CATALOG_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-                 "num_attention_heads": "num_heads",
-                 "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-                 "intermediate_size": "d_ff", "vocab_size": "vocab_size"}
 
 
 def model_config(config: Dict) -> ModelConfig:
-    """The catalog's configuration with the file's sizes; a size that
-    differs from the catalog must be listed in the file's ``reduced``."""
+    """The catalog's configuration with the file's sizes, the keys of its
+    family's ``CATALOG_KEYS``; a size that differs from the catalog must be
+    listed in the file's ``reduced``."""
+    keys = spec.family(config["family"]).CATALOG_KEYS
     base = get_config(config["catalog"])
-    sizes = {ours: config[theirs] for theirs, ours in _CATALOG_KEYS.items()}
-    for theirs, ours in _CATALOG_KEYS.items():
+    sizes = {ours: config[theirs] for theirs, ours in keys.items()}
+    for theirs, ours in keys.items():
         if sizes[ours] != getattr(base, ours) and theirs not in config["reduced"]:
             raise ValueError(f"{theirs} differs from the catalog's "
                              f"{config['catalog']} but is not in 'reduced'")
@@ -59,24 +56,10 @@ def model_config(config: Dict) -> ModelConfig:
 
 def params_fn(config: Dict, dims: Dict) -> Callable:
     """Seed words to the program's weights, as one jitted call."""
+    fam = spec.family(config["family"])
     dtype = jnp.dtype(config["dtype"])
-    return jax.jit(lambda w: program_params(weights.all_layers(w, dims, dtype)))
-
-
-def program_params(w: Dict) -> Dict:
-    """The benchmark's weight names laid out as the program's tree."""
-    lw = w["layers"]
-    return {
-        "embed": w["embed"],
-        "final_norm": {"scale": w["final_norm"]},
-        "layers": {
-            "ln1": {"scale": lw["attn_norm"]},
-            "ln2": {"scale": lw["mlp_norm"]},
-            "attn": {n: lw[n] for n in ("wq", "wk", "wv", "wo")},
-            "ffn": {"wi": lw["w_up"], "wg": lw["w_gate"],
-                    "wo": lw["w_down"]},
-        },
-    }
+    return jax.jit(lambda w: fam.program_params(
+        weights.all_layers(w, dims, dtype, fam)))
 
 
 @dataclasses.dataclass

@@ -25,22 +25,23 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def same_weights(server, cell, seed: int) -> bool:
-    """The reference's layer 1 and globals against the program's."""
+    """The reference's layer 1 and global leaves, laid out as the program's
+    tree by the family, against the program's."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
-    from bench import weights as W
-    from bench.reference import dense
+    from bench import check, weights as W
+    ref = check.reference(cell.config["family"])
     key, dtype = tuple(sorted(cell.dims.items())), cell.config["dtype"]
     words = W.seed_words(seed)
-    ref = dense._layer_weights(words, np.int32(1), key, dtype)
-    g = dense._global_weights(words, key, dtype)
+    want = cell.family.program_params(
+        {"layers": ref._layer_weights(words, np.int32(1), key, dtype),
+         **ref._global_weights(words, key, dtype)})
     p = server.params
-    pairs = [(ref["wq"], p["layers"]["attn"]["wq"][1]),
-             (ref["w_down"], p["layers"]["ffn"]["wo"][1]),
-             (ref["attn_norm"], p["layers"]["ln1"]["scale"][1]),
-             (g["embed"], p["embed"])]
-    return all(bool(jnp.array_equal(a, b.astype(jnp.float32)))
-               for a, b in pairs)
+    got = dict(p, layers=jax.tree.map(lambda a: a[1], p["layers"]))
+    return jax.tree.structure(want) == jax.tree.structure(got) and all(
+        bool(jnp.array_equal(a, b.astype(jnp.float32)))
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)))
 
 
 def main(argv=None) -> int:

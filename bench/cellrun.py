@@ -2,6 +2,11 @@
 from __future__ import annotations
 
 import dataclasses
+import os
+import shutil
+import sys
+import tempfile
+import time
 from typing import List, Optional
 
 import jax
@@ -69,6 +74,39 @@ def serve(server: adapter.Server, cell, seed: int, seconds: float,
         loop.tracer.close()
     attempted = len(loop.records) if backlog(cell) else len(requests)
     return Served(loop.records, loop.batches, compiles.count, attempted)
+
+
+def window(cell, seed: int, seconds: float, trace: bool, t_start: float,
+           keep: Optional[str] = None):
+    """Set-up and the window, timed from ``t_start``, the process's start.
+    Returns (setup_s, served, peak bytes, trace summary) and frees the
+    program's state on return.  With ``trace``, the stretch the cell
+    names is profiled and reduced after the peak is read
+    (``scopes.summarize`` over the base scopes and the family's); with
+    ``keep``, the serialized trace is also written to
+    ``<keep>/trace.xplane.pb``."""
+    from bench import scopes, trace_reduce
+    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+    try:
+        server = build(cell, seed)
+        setup_s = time.perf_counter() - t_start
+        served = serve(server, cell, seed, seconds, trace_dir=trace_dir)
+        peak = memory_peak()
+        summary = None
+        if trace:
+            t0 = time.perf_counter()
+            raw = trace_reduce.load(trace_dir)
+            if keep:
+                os.makedirs(keep, exist_ok=True)
+                with open(os.path.join(keep, "trace.xplane.pb"), "wb") as f:
+                    f.write(raw)
+            summary = scopes.summarize(raw, scopes.BASE + cell.family.SCOPES)
+            print(f"trace: {len(raw)} bytes reduced in "
+                  f"{time.perf_counter() - t0:.3f} s", file=sys.stderr)
+    finally:
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+    return setup_s, served, peak, summary
 
 
 def memory_peak() -> int:

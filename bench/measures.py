@@ -1,9 +1,11 @@
 """What the metric readers share: a run's records, and the work of the
-traced steps counted from their shapes (``bench.costs``)."""
+traced steps counted from their shapes by the cell's family
+(``bench/families/<family>.py``) and bounded by ``bench.costs``."""
 from __future__ import annotations
 
 import dataclasses
 import sys
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -17,13 +19,14 @@ DECODE = "jit_serve_decode"
 
 @dataclasses.dataclass
 class Run:
+    family: ModuleType      # bench/families/<family>.py: the counts
     dims: Dict
     seconds: float          # the measured window
     setup_s: float
     records: List           # adapter.Record, every request sent
     batches: List           # adapter.Batch
     peaks: Dict
-    trace: Optional[Dict] = None    # trace_reduce.reduce(...)
+    trace: Optional[Dict] = None    # scopes.summarize(...)
 
     def due_in_window(self) -> List:
         return [r for r in self.records if r.req.due < self.seconds]
@@ -37,21 +40,27 @@ def pct(values, q: float) -> Optional[float]:
     return float(np.percentile(values, q)) if len(values) else None
 
 
-def _calls(run: Run, kind: str) -> List[tuple]:
-    """(flops, bytes) of each traced call of one step, in order."""
+def decode_contexts(run: Run) -> List[List[int]]:
+    """Live cache entries of each row still owed a token, per traced decode
+    call, in order: step j of a batch serves the rows owed a (j + 2)th
+    token, over their prompt and the j + 1 tokens after it."""
     out = []
     for b in run.batches:
-        if not b.traced:
-            continue
-        if kind == PREFILL:
-            out.append((costs.prefill_flops(run.dims, b.lengths),
-                        costs.prefill_bytes(run.dims, b.lengths)))
-            continue
-        for j in range(max(b.outs) - 1):
-            ctx = [n + j + 1 for n, o in zip(b.lengths, b.outs) if j + 2 <= o]
-            out.append((costs.decode_flops(run.dims, ctx),
-                        costs.decode_bytes(run.dims, ctx)))
+        if b.traced:
+            out += [[n + j + 1 for n, o in zip(b.lengths, b.outs) if j + 2 <= o]
+                    for j in range(max(b.outs) - 1)]
     return out
+
+
+def _calls(run: Run, kind: str) -> List[tuple]:
+    """(flops, bytes) of each traced call of one step, in order."""
+    fam, dims = run.family, run.dims
+    if kind == PREFILL:
+        return [(fam.prefill_flops(dims, b.lengths),
+                 fam.prefill_bytes(dims, b.lengths))
+                for b in run.batches if b.traced]
+    return [(fam.decode_flops(dims, c), fam.decode_bytes(dims, c))
+            for c in decode_contexts(run)]
 
 
 def step_time(run: Run, kind: str) -> Optional[tuple]:

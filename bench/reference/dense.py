@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.families import dense as F
 
 EPS = 1e-6
 ROPE_THETA = 10_000.0
@@ -67,13 +68,14 @@ def _rope(x, positions):
 def _layer_weights(words, index, dims, dtype):
     """A layer as served (in ``dtype``), held in float32."""
     return jax.tree.map(lambda a: a.astype(jnp.float32),
-                        W.layer(words, index, dict(dims), jnp.dtype(dtype)))
+                        W.layer(words, index, dict(dims), jnp.dtype(dtype), F))
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "dtype"))
 def _global_weights(words, dims, dtype):
     return jax.tree.map(lambda a: a.astype(jnp.float32),
-                        W.global_leaves(words, dict(dims), jnp.dtype(dtype)))
+                        W.global_leaves(words, dict(dims), jnp.dtype(dtype),
+                                        F))
 
 
 @jax.jit

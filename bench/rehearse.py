@@ -35,9 +35,9 @@ def rehearse(name: str, chip) -> dict:
     import jax
     import jax.numpy as jnp
     from bench import adapter, check, spec
-    from bench.reference import dense
 
     cell = spec.load(name)
+    ref = check.reference(cell.config["family"])
     dims, b = cell.dims, max(cell.params["batcher"]["preferred"])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     words = sds((2,), jnp.uint32)
@@ -59,11 +59,11 @@ def rehearse(name: str, chip) -> dict:
                // (dims["heads"] * cell.max_len ** 2 * 4))
     key = tuple(sorted(dims.items()))
     w = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
-        lambda: dense._layer_weights(jnp.zeros(2, jnp.uint32), 0, key,
-                                     cell.config["dtype"])))
+        lambda: ref._layer_weights(jnp.zeros(2, jnp.uint32), 0, key,
+                                   cell.config["dtype"])))
     x = sds((rows, cell.max_len, dims["d"]), jnp.float32)
     out["reference_layer"] = _footprint(
-        dense._layer.lower(x, w, "f32").compile())
+        ref._layer.lower(x, w, "f32").compile())
     out["reference_rows"] = rows
     return out
 

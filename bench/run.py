@@ -10,8 +10,9 @@ checkout).  The window then serves the cell's traffic for ``--seconds``
 through ``bench.adapter``.  Afterwards the device's peak memory is read,
 the program's state is freed, and a sample of the served tokens is checked
 against the plain reference (``bench.check``).  With ``--trace 1`` a
-stretch at the window's end is profiled and the cell's per-layer metrics
-are printed; otherwise its end-to-end metrics.  The last line of standard
+stretch at the window's end is profiled, reduced to device time by step,
+op and the program's scopes (``bench.scopes``), and the cell's per-layer
+metrics are printed; otherwise its end-to-end metrics.  The last line of standard
 output is one JSON object; the numbers compared for ``correct`` are the
 last lines of standard error.  Without a TPU, or with fewer chips than the
 cell asks for, it exits with 1 and prints no result.
@@ -24,9 +25,7 @@ T_START = time.perf_counter()
 
 import argparse
 import json
-import shutil
 import sys
-import tempfile
 from pathlib import Path
 from typing import Dict
 
@@ -34,31 +33,13 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
-def _window(cell, seed: int, seconds: float, trace: bool):
-    """Set-up and the window.  Returns what outlives the program's state,
-    which is freed when this returns."""
-    from bench import cellrun, trace_reduce
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
-    try:
-        server = cellrun.build(cell, seed)
-        setup_s = time.perf_counter() - T_START
-        served = cellrun.serve(server, cell, seed, seconds,
-                               trace_dir=trace_dir)
-        peak = cellrun.memory_peak()
-        summary = (trace_reduce.reduce(trace_reduce.load(trace_dir))
-                   if trace else None)
-    finally:
-        if trace_dir:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-    return setup_s, served, peak, summary
-
-
 def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: Dict,
              device: Dict) -> Dict:
     """One run of a cell: set-up, window, check; the result line's object."""
-    from bench import check, spec
+    from bench import cellrun, check, spec
     from bench.measures import Run
-    setup_s, served, peak, summary = _window(cell, seed, seconds, trace)
+    setup_s, served, peak, summary = cellrun.window(cell, seed, seconds, trace,
+                                                    T_START)
     chk = cell.params["check"]
     picked = check.sample(served.records, seed, chk["served_tokens"])
     gap = (check.served_gaps(cell, seed, picked)["served"]
@@ -66,9 +47,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: Dict,
     print(f"checked {sum(r.req.out_len for r in picked)} served tokens of "
           f"{len(picked)} requests", file=sys.stderr)
     checks = check.checks(gap, served, chk["max_logit_gap"])
-    run = Run(dims=cell.dims, seconds=seconds, setup_s=setup_s,
-              records=served.records, batches=served.batches, peaks=peaks,
-              trace=summary)
+    run = Run(family=cell.family, dims=cell.dims, seconds=seconds,
+              setup_s=setup_s, records=served.records,
+              batches=served.batches, peaks=peaks, trace=summary)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = spec.reader(m["name"])(run)

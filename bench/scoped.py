@@ -23,39 +23,31 @@ import argparse
 import dataclasses
 import json
 import os
-import shutil
 import sys
-import tempfile
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-def _keep(raw: bytes, served, keep: str) -> None:
-    os.makedirs(keep, exist_ok=True)
-    with open(os.path.join(keep, "trace.xplane.pb"), "wb") as f:
-        f.write(raw)
-    with open(os.path.join(keep, "batches.json"), "w") as f:
-        json.dump([dataclasses.asdict(b) for b in served.batches], f)
 
-
-def breakdown(summary, red) -> dict:
+def breakdown(summary) -> dict:
     """Per step: calls, module, busy and self seconds, scopes largest
     first, and the costliest unscoped ops; and the trace's costliest ops
-    with their scope."""
+    with their scope (``summary`` as ``scopes.summarize`` gives it)."""
     from bench import scopes
     steps, named = {}, {}
-    for step, by_scope in red["scopes"].items():
+    for step, by_scope in summary["scopes"].items():
+        ops = summary["scope_ops"][step]
         calls, module_s = summary["modules"].get(step, (0, 0.0))
-        unscoped = sorted(((o, t) for o, (s, t) in red["ops"][step].items()
+        unscoped = sorted(((o, t) for o, (s, t) in ops.items()
                            if s == scopes.UNSCOPED), key=lambda x: -x[1])
         steps[step] = {
             "calls": calls, "module_s": module_s,
-            "busy_s": red["busy_s"][step],
+            "busy_s": summary["step_busy_s"][step],
             "self_s": sum(by_scope.values()),
             "scopes": sorted(by_scope.items(), key=lambda x: -x[1]),
             "unscoped_ops": unscoped[:10]}
-        for op, (scope, _) in red["ops"][step].items():
+        for op, (scope, _) in ops.items():
             named.setdefault(op, f"{step.rsplit('_', 1)[1]}/{scope}")
     ops = [[f"{named.get(op, scopes.UNSCOPED)}|{op}", t]
            for op, t in summary["device_ops"]]
@@ -82,26 +74,16 @@ def main(argv=None) -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
-    from jax.profiler import ProfileData
-    from bench import cellrun, scopes, trace_reduce
+    from bench import cellrun, scopes
     from bench.measures import Run
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
-    try:
-        server = cellrun.build(cell, args.seed)
-        setup_s = time.perf_counter() - T_START
-        served = cellrun.serve(server, cell, args.seed, args.seconds,
-                               trace_dir=trace_dir)
-        raw = scopes.load(trace_dir)
-        if args.keep:
-            _keep(raw, served, args.keep)
-        summary = trace_reduce.reduce(ProfileData.from_serialized_xspace(raw))
-        red = scopes.reduce(raw)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-    summary["scopes"] = red["scopes"]
-    run = Run(dims=cell.dims, seconds=args.seconds, setup_s=setup_s,
-              records=served.records, batches=served.batches, peaks=peaks,
-              trace=summary)
+    setup_s, served, _, summary = cellrun.window(
+        cell, args.seed, args.seconds, True, T_START, keep=args.keep)
+    if args.keep:
+        with open(os.path.join(args.keep, "batches.json"), "w") as f:
+            json.dump([dataclasses.asdict(b) for b in served.batches], f)
+    run = Run(family=cell.family, dims=cell.dims, seconds=args.seconds,
+              setup_s=setup_s, records=served.records,
+              batches=served.batches, peaks=peaks, trace=summary)
     metrics = {m["name"]: spec.reader(m["name"])(run)
                for m in cell.end_to_end + cell.per_layer}
     metrics.update({k: f(run) for k, f in scopes.READERS.items()})
@@ -111,7 +93,7 @@ def main(argv=None) -> int:
                    "busy_s": summary["busy_s"],
                    "window_s": summary["window_s"]},
         "compiles_in_window": served.compiles,
-        "metrics": metrics, **breakdown(summary, red)}))
+        "metrics": metrics, **breakdown(summary)}))
     return 0
 
 

@@ -7,10 +7,11 @@ the TPU profiler carries it as the ``tf_op`` stat (``<path>:<type>``) of
 each ``XLA Ops`` event's metadata.  ``ProfileData`` does not show metadata
 stats, so they are read from the serialized trace with a schema of the few
 XSpace fields they need (``_space``).  An op's scope is the
-innermost component of that path that is in ``SCOPES``, the benchmark's own
-copy of the names; an op with none is ``unscoped``.  A fusion of
-instructions from several scopes carries its root's metadata, so it counts
-under its root's scope.
+innermost component of that path that is among the names it is given:
+``BASE``, the scopes every served step carries, and the ``SCOPES`` of the
+cell's family (``bench/families/<family>.py``); an op with none is
+``unscoped``.  A fusion of instructions from several scopes carries its
+root's metadata, so it counts under its root's scope.
 
 An op's self time is its duration less the part that ops nested inside it
 on the same line cover: a ``while`` gets only what its body's ops leave
@@ -24,29 +25,26 @@ from __future__ import annotations
 import bisect
 import collections
 import functools
-import glob
-import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from jax.profiler import ProfileData
 
-from bench import costs
-from bench.measures import DECODE, PREFILL, step_time
+from bench import costs, trace_reduce
+from bench.measures import DECODE, PREFILL, decode_contexts, step_time
 from bench.trace_reduce import (DEVICE_PREFIX, MODULES, OPS, SPAN_PREFIX,
                                 _SUFFIX, _merge)
 
-SCOPES = ("embed", "attn_qkv", "kv_write", "attn_core", "attn_out", "mlp",
-          "moe", "logits", "sample", "rglru", "rwkv_time_mix",
-          "rwkv_channel_mix")
+# named outside the layers: the embedding, the output head, sampling
+BASE = ("embed", "logits", "sample")
 UNSCOPED = "unscoped"
 STEPS = (PREFILL, DECODE)
 PATH_STAT = "tf_op"
 
 
-def scope_of(path: str) -> str:
-    """The innermost component of an op-name path that names a scope."""
+def scope_of(path: str, names: Sequence[str]) -> str:
+    """The innermost component of an op-name path that is in ``names``."""
     for part in reversed(path.split("/")):
-        if part in SCOPES:
+        if part in names:
             return part
     return UNSCOPED
 
@@ -139,23 +137,21 @@ def metadata_paths(serialized: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
-def load(trace_dir: str) -> bytes:
-    """The serialized trace the profiler wrote under ``trace_dir``."""
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if len(paths) != 1:
-        raise FileNotFoundError(f"{len(paths)} traces under {trace_dir}")
-    with open(paths[0], "rb") as f:
-        return f.read()
-
-
-def reduce(serialized: bytes) -> Optional[Dict]:
-    """Self seconds of each served step by scope and by op, and each
-    step's busy seconds, averaged over the devices traced:
-    ``{"scopes": {step: {scope: s}}, "ops": {step: {op: [scope, s]}},
-    "busy_s": {step: s}}``.  None where the trace holds no device or no
-    harness span."""
+def summarize(serialized: bytes, names: Sequence[str]) -> Optional[Dict]:
+    """``trace_reduce.reduce``'s summary of the trace, with each served
+    step's self seconds by scope and by op, and its busy seconds, averaged
+    over the devices traced: ``"scopes": {step: {scope: s}}``,
+    ``"scope_ops": {step: {op: [scope, s]}}``, ``"step_busy_s": {step: s}``.
+    None where the trace holds no device or no harness span."""
     pd = ProfileData.from_serialized_xspace(serialized)
+    summary = trace_reduce.reduce(pd)
+    red = _reduce(pd, serialized, frozenset(names))
+    if summary is not None and red is not None:
+        summary.update(red)
+    return summary
+
+
+def _reduce(pd, serialized: bytes, names) -> Optional[Dict]:
     window = _window(pd)
     devices = [plane for plane in pd.planes
                if plane.name.startswith(DEVICE_PREFIX)]
@@ -183,32 +179,16 @@ def reduce(serialized: bytes) -> Optional[Dict]:
             spans = [(e.start_ns, e.end_ns) for e in events]
             busy[step] += sum(e - s for s, e in _merge(spans)) * 1e-9
             for e, t in zip(events, self_times(spans)):
-                scope = scope_of(named.get(e.name, ""))
+                scope = scope_of(named.get(e.name, ""), names)
                 scopes[step][scope] += t * 1e-9
                 _, seen = ops[step].get(e.name, (scope, 0.0))
                 ops[step][e.name] = [scope, seen + t * 1e-9]
     n = len(devices)
     return {"scopes": {k: {s: t / n for s, t in v.items()}
                        for k, v in scopes.items()},
-            "ops": {k: {o: [s, t / n] for o, (s, t) in v.items()}
-                    for k, v in ops.items()},
-            "busy_s": {k: t / n for k, t in busy.items()}}
-
-
-# ----------------------------------------------------------------- counts
-def decode_attn_counts(dims: Dict, contexts: Sequence[int]
-                       ) -> Tuple[float, float]:
-    """(operations, bytes) of one decode call's attention: scores and
-    weighted values over the live entries of each row still owed a token,
-    and those entries' keys and values read once."""
-    live = sum(contexts)
-    return (costs._attn_flops(dims, live),
-            live * costs.kv_bytes_per_token(dims))
-
-
-def prefill_attn_flops(dims: Dict, lengths: Sequence[int]) -> float:
-    """Causal attention over each prompt's live (query, key) pairs."""
-    return costs._attn_flops(dims, sum(n * (n + 1) / 2 for n in lengths))
+            "scope_ops": {k: {o: [s, t / n] for o, (s, t) in v.items()}
+                          for k, v in ops.items()},
+            "step_busy_s": {k: t / n for k, t in busy.items()}}
 
 
 # ---------------------------------------------------------------- readers
@@ -227,17 +207,6 @@ def ms_per_call(run, step: str, scope: str) -> Optional[float]:
     return None if t is None else 1e3 * t / step_time(run, step)[0]
 
 
-def _contexts(run) -> List[List[int]]:
-    """Live cache entries of each row still owed a token, per traced
-    decode call, in the order ``measures._calls`` counts them."""
-    out = []
-    for b in run.batches:
-        if b.traced:
-            out += [[n + j + 1 for n, o in zip(b.lengths, b.outs) if j + 2 <= o]
-                    for j in range(max(b.outs) - 1)]
-    return out
-
-
 def decode_attn_roofline(run) -> Optional[float]:
     """Least time of the traced decode calls' attention (live pairs at
     peak, or live entries at peak bandwidth, whichever is longer), as a
@@ -245,8 +214,9 @@ def decode_attn_roofline(run) -> Optional[float]:
     t = scope_s(run, DECODE, "attn_core")
     if not t:
         return None
-    bound = sum(costs.bound_s(*decode_attn_counts(run.dims, c), run.peaks)
-                for c in _contexts(run))
+    counts = run.family.decode_attn_counts
+    bound = sum(costs.bound_s(*counts(run.dims, c), run.peaks)
+                for c in decode_contexts(run))
     return 100.0 * bound / t
 
 
@@ -256,7 +226,7 @@ def prefill_attn_roofline(run) -> Optional[float]:
     t = scope_s(run, PREFILL, "attn_core")
     if not t:
         return None
-    flops = sum(prefill_attn_flops(run.dims, b.lengths)
+    flops = sum(run.family.prefill_attn_flops(run.dims, b.lengths)
                 for b in run.batches if b.traced)
     return 100.0 * flops / run.peaks["bf16_flops"] / t
 

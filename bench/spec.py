@@ -3,15 +3,33 @@
 A cell ``<name>`` is ``bench/workloads/<name>.json`` (rate, batcher, check
 limits, trace stretch); its configuration is ``bench/configs/<config>.json``
 and its traffic mix ``bench/traffic/<traffic>.json``; each metric it
-reports is read by ``bench/metrics/<metric>.py``.  Adding any of them is
-adding files and entries: nothing here names one.
+reports is read by ``bench/metrics/<metric>.py``.  The configuration's
+``family`` names two modules: ``bench/families/<family>.py``, all that the
+harness knows of the family's block (the file's keys, the sizes, the
+seeded leaves and their layout in the program's tree, the operations and
+bytes of the served steps, the scopes its layers carry), and
+``bench/reference/<family>.py``, the plain forward pass that decides
+``correct``.
+
+Adding a configuration of a new family is adding files and entries in
+``BENCHMARK.json``, with no edit to a file that is there:
+
+- ``bench/configs/<name>.json``
+- ``bench/families/<family>.py``
+- ``bench/reference/<family>.py``
+- ``bench/workloads/<cell>.json``
+- ``bench/metrics/<metric>.py`` for a metric no file reads yet
+
+Nothing here names one of them.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 BENCH = Path(__file__).resolve().parent
@@ -29,13 +47,14 @@ class Cell:
     per_layer: List[Dict]   # the per-layer metrics this cell reports
 
     @property
+    def family(self) -> ModuleType:
+        return family(self.config["family"])
+
+    @property
     def dims(self) -> Dict:
-        c = self.config
-        return {"layers": c["num_hidden_layers"], "d": c["hidden_size"],
-                "heads": c["num_attention_heads"],
-                "kv_heads": c["num_key_value_heads"],
-                "head_dim": c["head_dim"], "ff": c["intermediate_size"],
-                "vocab": c["vocab_size"]}
+        """The sizes the harness works with; every family gives at least
+        ``layers``, ``d``, ``vocab`` and ``heads``."""
+        return self.family.dims(self.config)
 
     @property
     def pad(self) -> int:
@@ -59,6 +78,11 @@ def _json(path: Path) -> Dict:
 
 def _reports(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
+
+
+def family(name: str) -> ModuleType:
+    """``bench/families/<name>.py``."""
+    return importlib.import_module(f"bench.families.{name}")
 
 
 def load(name: str, root: Path = ROOT) -> Cell:

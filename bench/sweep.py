@@ -31,8 +31,9 @@ def summary(served, cell, seconds: float, rate: float, order: int) -> dict:
     recs = sorted(served.records, key=lambda r: r.req.due)
     ttft = np.array([r.first_token - r.req.due for r in recs])
     half = len(recs) // 2
-    run = Run(dims=cell.dims, seconds=seconds, setup_s=0.0,
-              records=served.records, batches=served.batches, peaks={})
+    run = Run(family=cell.family, dims=cell.dims, seconds=seconds,
+              setup_s=0.0, records=served.records, batches=served.batches,
+              peaks={})
     return {"rate": rate, "order": order, "due": served.attempted,
             "answered_by_close": sum(r.done <= seconds for r in recs),
             "answered_rate": sum(r.done <= seconds for r in recs) / seconds,

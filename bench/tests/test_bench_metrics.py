@@ -9,6 +9,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import costs, spec  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.adapter import Batch, Record  # noqa: E402
 from bench.generator import Request  # noqa: E402
 from bench.measures import DECODE, PREFILL, Run  # noqa: E402
@@ -33,8 +34,8 @@ def run():
     recs = [rec(due=i, dispatch=i + 0.1 * i, first=i + 0.2 * i + 0.5,
                 done=i + 0.2 * i + 2.5, out=1 + i) for i in range(10)]
     recs.append(rec(due=10.5, dispatch=11, first=12, done=13, out=5))
-    return Run(dims=DIMS, seconds=10.0, setup_s=3.25, records=recs,
-               batches=[], peaks=PEAKS)
+    return Run(family=dense, dims=DIMS, seconds=10.0, setup_s=3.25,
+               records=recs, batches=[], peaks=PEAKS)
 
 
 def read(name, run):
@@ -69,8 +70,8 @@ def traced_run(decode_calls, prefill_calls=1, busy=0.3):
     trace = {"window_s": 1.0, "busy_s": busy,
              "modules": {DECODE: (decode_calls, 0.03),
                          PREFILL: (prefill_calls, 0.02)}}
-    return Run(dims=DIMS, seconds=10.0, setup_s=1.0, records=[],
-               batches=[b, untraced], peaks=PEAKS, trace=trace)
+    return Run(family=dense, dims=DIMS, seconds=10.0, setup_s=1.0,
+               records=[], batches=[b, untraced], peaks=PEAKS, trace=trace)
 
 
 def test_decode_step_and_roofline():
@@ -78,25 +79,25 @@ def test_decode_step_and_roofline():
     assert read("decode_step_ms", run) == pytest.approx(10.0)
     # step j serves the rows still owed a token: (5+1, 3+1), (5+2,), (5+3,)
     ctx = [[6, 4], [7], [8]]
-    bound = sum(costs.bound_s(costs.decode_flops(DIMS, c),
-                              costs.decode_bytes(DIMS, c), PEAKS) for c in ctx)
+    bound = sum(costs.bound_s(dense.decode_flops(DIMS, c),
+                              dense.decode_bytes(DIMS, c), PEAKS) for c in ctx)
     assert read("decode_roofline", run) == pytest.approx(100 * bound / 0.03)
 
 
 def test_prefill_roofline_counts_live_tokens():
     run = traced_run(decode_calls=3)
     assert read("prefill_ms", run) == pytest.approx(20.0)
-    f = costs.prefill_flops(DIMS, [5, 3])
+    f = dense.prefill_flops(DIMS, [5, 3])
     nonembed = 2 * (2 * 8 * 2 * 4 + 2 * 8 * 1 * 4 + 3 * 8 * 16 + 2 * 8) + 8
     assert f == 2 * nonembed * 8 + 4 * 2 * 2 * 4 * (15 + 6) + 2 * 2 * 8 * 32
-    bound = costs.bound_s(f, costs.prefill_bytes(DIMS, [5, 3]), PEAKS)
+    bound = costs.bound_s(f, dense.prefill_bytes(DIMS, [5, 3]), PEAKS)
     assert read("prefill_roofline", run) == pytest.approx(100 * bound / 0.02)
 
 
 def test_step_mfu_and_idle():
     run = traced_run(decode_calls=3)
-    flops = costs.prefill_flops(DIMS, [5, 3]) + sum(
-        costs.decode_flops(DIMS, c) for c in ([6, 4], [7], [8]))
+    flops = dense.prefill_flops(DIMS, [5, 3]) + sum(
+        dense.decode_flops(DIMS, c) for c in ([6, 4], [7], [8]))
     want = 100 * flops / (0.05 * PEAKS["bf16_flops"])
     assert read("step_mfu.chat", run) == pytest.approx(want)
     assert read("step_mfu.long", run) == pytest.approx(want)

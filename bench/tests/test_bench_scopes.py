@@ -13,6 +13,7 @@ from jax.profiler import ProfileData  # noqa: E402
 
 from bench import scopes, trace_reduce  # noqa: E402
 from bench.adapter import Batch  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.measures import DECODE, PREFILL, Run  # noqa: E402
 
 FIXTURE = Path(__file__).parent / "fixtures" / "scoped_trace.textproto"
@@ -25,6 +26,7 @@ FIXTURE = Path(__file__).parent / "fixtures" / "scoped_trace.textproto"
 RECORDED = Path(__file__).parent / "fixtures" / "recorded_chat.xplane.pb"
 DENSE = {"embed", "attn_qkv", "kv_write", "attn_core", "attn_out", "mlp",
          "logits", "sample"}
+NAMES = scopes.BASE + dense.SCOPES
 US = 1e-6
 DIMS = {"layers": 2, "d": 8, "heads": 2, "kv_heads": 1, "head_dim": 4,
         "ff": 16, "vocab": 32}
@@ -42,18 +44,17 @@ def pd():
 
 @pytest.fixture(scope="module")
 def red():
-    return scopes.reduce(serialized(FIXTURE.read_text()))
+    return scopes.summarize(serialized(FIXTURE.read_text()), NAMES)
 
 
-def traced_run(pd, red, outs=(3, 2)):
+def traced_run(red, outs=(3, 2)):
     """One traced batch of two prompts (3 and 2 tokens): one prefill and
     max(outs) - 1 decode calls."""
-    summary = trace_reduce.reduce(pd)
-    summary["scopes"] = red["scopes"]
-    return Run(dims=DIMS, seconds=1.0, setup_s=0.0, records=[],
+    return Run(family=dense, dims=DIMS, seconds=1.0, setup_s=0.0,
+               records=[],
                batches=[Batch(lengths=[3, 2], outs=list(outs), dispatch=0.0,
                               traced=True)],
-               peaks=PEAKS, trace=summary)
+               peaks=PEAKS, trace=dict(red))
 
 
 @pytest.mark.parametrize("path,scope", [
@@ -68,7 +69,7 @@ def traced_run(pd, red, outs=(3, 2)):
     ("jit(serve_decode)/attn_core_extra/dot_general", "unscoped"),
 ])
 def test_scope_is_the_innermost_known_component(path, scope):
-    assert scopes.scope_of(path) == scope
+    assert scopes.scope_of(path, NAMES) == scope
 
 
 def test_self_time_leaves_a_container_what_its_body_does_not_cover():
@@ -90,34 +91,36 @@ def test_self_times_by_scope(red):
 
 def test_self_times_add_up_to_busy_time_and_the_module_adds_its_gaps(pd, red):
     modules = trace_reduce.reduce(pd)["modules"]
-    assert red["busy_s"] == pytest.approx({PREFILL: 1.9 * US, DECODE: 1.6 * US})
-    for step, busy in red["busy_s"].items():
+    assert red["step_busy_s"] == pytest.approx({PREFILL: 1.9 * US, DECODE: 1.6 * US})
+    for step, busy in red["step_busy_s"].items():
         assert sum(red["scopes"][step].values()) == pytest.approx(busy)
         assert busy <= modules[step][1]
-    assert modules[PREFILL][1] - red["busy_s"][PREFILL] == pytest.approx(0.1 * US)
+    assert modules[PREFILL][1] - red["step_busy_s"][PREFILL] == pytest.approx(0.1 * US)
 
 
 def test_ops_outside_the_served_steps_or_the_window_do_not_count(red):
     assert set(red["scopes"]) == {PREFILL, DECODE}
-    assert "fusion.11" not in red["ops"][PREFILL]
-    assert "fusion.11" not in red["ops"][DECODE]
+    assert "fusion.11" not in red["scope_ops"][PREFILL]
+    assert "fusion.11" not in red["scope_ops"][DECODE]
     # the decode at 8.5 us, after the window, adds nothing
-    assert red["ops"][DECODE]["fusion.7"] == ["attn_core", pytest.approx(0.4 * US)]
+    assert red["scope_ops"][DECODE]["fusion.7"] == ["attn_core", pytest.approx(0.4 * US)]
 
 
 def test_ops_keep_their_scope(red):
-    assert red["ops"][DECODE]["copy.10"] == ["unscoped", pytest.approx(0.2 * US)]
-    assert red["ops"][PREFILL]["while.1"] == ["unscoped", pytest.approx(0.3 * US)]
+    assert red["scope_ops"][DECODE]["copy.10"] == ["unscoped", pytest.approx(0.2 * US)]
+    assert red["scope_ops"][PREFILL]["while.1"] == ["unscoped", pytest.approx(0.3 * US)]
 
 
 def test_no_device_or_no_span_gives_nothing():
     text = FIXTURE.read_text()
-    assert scopes.reduce(serialized("planes {" + text.split("planes {")[2])) is None
-    assert scopes.reduce(serialized(text.split("planes {\n  id: 2")[0])) is None
+    assert scopes.summarize(serialized("planes {" + text.split("planes {")[2]),
+                            NAMES) is None
+    assert scopes.summarize(serialized(text.split("planes {\n  id: 2")[0]),
+                            NAMES) is None
 
 
-def test_metrics_per_call(pd, red):
-    run = traced_run(pd, red)
+def test_metrics_per_call(red):
+    run = traced_run(red)
     ms = 1e3 * US
     read = {k: f(run) for k, f in scopes.READERS.items()}
     assert read["decode_attn_ms"] == pytest.approx(0.2 * ms)
@@ -127,8 +130,8 @@ def test_metrics_per_call(pd, red):
     assert read["prefill_logits_ms"] == pytest.approx(0.3 * ms)
 
 
-def test_attention_rooflines(pd, red):
-    run = traced_run(pd, red)
+def test_attention_rooflines(red):
+    run = traced_run(red)
     # decode calls attend over 4 + 3 and then 5 live entries; per entry
     # 4 x 2 layers x 2 heads x 4 = 64 operations and 2 x 2 x 1 x 4 x 2 =
     # 32 bytes, so bytes bound both calls: (7 + 5) x 32 / 1e10 s
@@ -139,21 +142,21 @@ def test_attention_rooflines(pd, red):
         100 * 9 * 64 / 1e12 / (0.5 * US))
 
 
-def test_metrics_are_none_where_calls_disagree_or_scopes_are_missing(pd, red):
-    run = traced_run(pd, red, outs=(4, 2))      # three decode calls made
+def test_metrics_are_none_where_calls_disagree_or_scopes_are_missing(red):
+    run = traced_run(red, outs=(4, 2))      # three decode calls made
     assert all(scopes.READERS[k](run) is None
                for k in scopes.READERS if k.startswith("decode"))
     assert scopes.READERS["prefill_attn_ms"](run) == pytest.approx(0.5e-3)
-    run = traced_run(pd, red)
+    run = traced_run(red)
     del run.trace["scopes"]
     assert all(f(run) is None for f in scopes.READERS.values())
     run.trace = None
     assert all(f(run) is None for f in scopes.READERS.values())
 
 
-def test_breakdown_names_each_op_by_step_and_scope(pd, red):
+def test_breakdown_names_each_op_by_step_and_scope(red):
     from bench import scoped
-    got = scoped.breakdown(trace_reduce.reduce(pd), red)
+    got = scoped.breakdown(red)
     # values and order are trace_reduce's; names gain step and scope
     assert got["device_ops"][:2] == [
         ["prefill/unscoped|while.1", pytest.approx(1.6 * US)],
@@ -170,7 +173,7 @@ def test_breakdown_names_each_op_by_step_and_scope(pd, red):
 @pytest.fixture(scope="module")
 def recorded():
     raw = RECORDED.read_bytes()
-    return ProfileData.from_serialized_xspace(raw), scopes.reduce(raw)
+    return ProfileData.from_serialized_xspace(raw), scopes.summarize(raw, NAMES)
 
 
 def test_recorded_trace_has_the_planes_lines_and_modules_assumed(recorded):
@@ -201,6 +204,6 @@ def test_recorded_self_times_add_up_to_each_steps_device_time(recorded):
     modules = trace_reduce.reduce(pd)["modules"]
     for step in (PREFILL, DECODE):
         total = sum(red["scopes"][step].values())
-        assert total == pytest.approx(red["busy_s"][step], rel=1e-9)
+        assert total == pytest.approx(red["step_busy_s"][step], rel=1e-9)
         assert total == pytest.approx(modules[step][1], rel=1e-3)
     assert red["scopes"][PREFILL]["attn_core"] > red["scopes"][PREFILL]["logits"]

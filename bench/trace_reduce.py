@@ -29,12 +29,16 @@ TOP = 10
 _SUFFIX = re.compile(r"\(\d+\)$")
 
 
-def load(trace_dir: str) -> ProfileData:
+def load(trace_dir: str) -> bytes:
+    """The serialized trace the profiler wrote under ``trace_dir``: what
+    ``ProfileData.from_serialized_xspace`` reads, and the op metadata that
+    ``bench.scopes`` reads beside it."""
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if len(paths) != 1:
         raise FileNotFoundError(f"{len(paths)} traces under {trace_dir}")
-    return ProfileData.from_file(paths[0])
+    with open(paths[0], "rb") as f:
+        return f.read()
 
 
 def _merge(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
