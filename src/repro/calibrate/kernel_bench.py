@@ -87,8 +87,7 @@ def _make_flash(batch: int, seq: int, dtype: str, seed: int):
     q = _rand(ks[0], (batch, heads, seq, d), dtype)
     k = _rand(ks[1], (batch, kv_heads, seq, d), dtype)
     v = _rand(ks[2], (batch, kv_heads, seq, d), dtype)
-    block = min(128, seq)
-    return (q, k, v), {"causal": True, "block_q": block, "block_k": block}
+    return (q, k, v), {"causal": True}
 
 
 def _make_decode(batch: int, context: int, dtype: str, seed: int):

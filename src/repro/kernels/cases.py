@@ -1,9 +1,10 @@
 """Each Pallas kernel at real widths, with its reference and tolerance.
 
 One case per kernel of this package, at the widths it meets in a model:
-granite-3-2b's attention (32 query heads, 8 KV heads, head_dim 64),
-rwkv6-7b's WKV (64 heads of 64), an RG-LRU recurrence of width 4096 and a
-512×2048×8192 int8 matmul.  ``tests/test_tpu_compile.py`` compiles every
+the long cells' prefill attention (32 query heads over 8 KV heads,
+prompts right-padded to 2048: granite-3-2b's batch of 4 at head_dim 64,
+and granite-8b's batch of 2 at 128), rwkv6-7b's WKV (64 heads of 64), an
+RG-LRU recurrence of width 4096 and a 512×2048×8192 int8 matmul.  ``tests/test_tpu_compile.py`` compiles every
 case for a TPU v5e from the shapes of ``make``; ``chip_smoke.py`` runs
 every case on the chip against ``ref`` (evaluated at float32 matmul
 precision).  Tolerances are those of the interpret-mode tests in
@@ -41,11 +42,29 @@ def _normal(key, i, shape, dtype=jnp.float32, scale=1.0):
             * scale).astype(dtype)
 
 
-def _attention_inputs(key):          # granite-3-2b, one 2048-token prompt
-    bf = jnp.bfloat16
-    return (_normal(key, 0, (1, 32, 2048, 64), bf),
-            _normal(key, 1, (1, 8, 2048, 64), bf),
-            _normal(key, 2, (1, 8, 2048, 64), bf))
+def _attention_inputs(batch, head_dim):
+    def make(key):                   # prompts of 1024-2048 padded to 2048
+        bf = jnp.bfloat16
+        lengths = jax.random.randint(jax.random.fold_in(key, 3), (batch,),
+                                     1024, 2049)
+        return (_normal(key, 0, (batch, 32, 2048, head_dim), bf),
+                _normal(key, 1, (batch, 8, 2048, head_dim), bf),
+                _normal(key, 2, (batch, 8, 2048, head_dim), bf),
+                lengths.astype(jnp.int32))
+    return make
+
+
+def _live_rows(attention):
+    """Prefill attention with each row's padding queries zeroed: only the
+    live ones have an answer to compare."""
+    def f(q, k, v, lengths, **kw):
+        live = jnp.arange(q.shape[2])[:, None] < lengths[:, None, None, None]
+        return jnp.where(live, attention(q, k, v, lengths, **kw), 0)
+    return f
+
+
+def _attention_reference(q, k, v, lengths):
+    return ref.mha_reference(q, k, v, causal=True, lengths=lengths)
 
 
 def _decode_inputs(key):             # granite-3-2b, batch 8, 2048-slot cache
@@ -82,9 +101,13 @@ def _int8_inputs(key):               # 512 tokens × (2048 → 8192)
 
 CASES: Dict[str, KernelCase] = {
     "flash_attention": KernelCase(
-        functools.partial(_fa.flash_attention, causal=True),
-        functools.partial(ref.mha_reference, causal=True),
-        _attention_inputs, atol=2e-2, rtol=2e-1),
+        _live_rows(functools.partial(_fa.flash_attention, causal=True)),
+        _live_rows(_attention_reference), _attention_inputs(4, 64),
+        atol=2e-2, rtol=2e-1),
+    "flash_attention_hd128": KernelCase(
+        _live_rows(functools.partial(_fa.flash_attention, causal=True)),
+        _live_rows(_attention_reference), _attention_inputs(2, 128),
+        atol=2e-2, rtol=2e-1),
     "decode_attention": KernelCase(
         _dec.decode_attention, ref.decode_attention_reference,
         _decode_inputs, atol=2e-2, rtol=2e-1),

@@ -1,130 +1,196 @@
 """Flash attention (prefill) Pallas TPU kernel.
 
-Blockwise online-softmax attention with GQA, sliding-window and logit
-soft-capping.  Tiling: grid = (B, H, Sq/bq, Skv/bk); the kv axis is the
-fastest (sequentially iterated on TPU), with the running max / sum / output
-accumulator held in VMEM scratch.  Block shapes are MXU-aligned (128).
+Blockwise online-softmax self-attention over right-padded rows, with GQA,
+sliding windows and logit soft-capping.  Grid = (B, K, S/bq, S/bk); the
+K/V axis is the fastest (sequentially iterated on TPU), with the running
+max, sum and output accumulator of each query block held in VMEM scratch.
 
-Causal + window structure is exploited: fully-masked kv blocks are skipped
-(no FLOPs issued), which is what makes the local-attention layers of
-gemma2 / recurrentgemma pay O(S·W) instead of O(S²).
+* **GQA folding.**  A grid step takes all G = H/K query heads that share a
+  KV head, a (G·bq, d) query tile against one (bk, d) K/V tile, so K and V
+  are read once per group and the matmuls are G times taller.
+* **Operands.**  The MXU takes q, k and v in their own dtype, and the
+  probabilities in v's dtype, accumulating in float32; the softmax
+  statistics stay float32.
+* **Block skipping** (``_span``): a K/V block wholly above the causal
+  diagonal, out of every query's window, or at or past the row's length
+  (``lengths``, scalar-prefetched) does no work, and its K/V index is
+  clamped to a block that runs, so no DMA is issued for it.  A query block
+  wholly past the row's length writes zeros.  Only blocks that cross the
+  diagonal, the window's edge or the length build the element mask.
+
+A live query never sees a padded key under a causal mask with right
+padding; a padded query in a live block sees the row's live keys, so every
+output is finite.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+MIN_BLOCK = 128          # the chip's lane width: blocks are multiples of it
 NEG_INF = -1e30
 
+# (block_q, block_k) aimed for; ``block_sizes`` takes the largest multiples
+# of MIN_BLOCK up to these that divide the sequence.  Checked only by
+# compiling for a described TPU v5e at the benchmark's prefill shapes (32
+# query heads over 8 KV heads, head sizes 64 and 128, 768 and 2048 tokens),
+# where it fits the default scoped VMEM; not chosen by a run on the chip.
+BLOCKS = (256, 512)
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                  causal: bool, window: int, softcap: float, sm_scale: float,
-                  block_q: int, block_k: int, kv_len: int):
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nkv = pl.num_programs(3)
 
-    @pl.when(kj == 0)
+def block_sizes(seq_len: int) -> Tuple[int, int]:
+    """(block_q, block_k) for a sequence length.  A sequence that is not a
+    multiple of MIN_BLOCK is one block (interpret mode)."""
+    def fit(target):
+        if seq_len % MIN_BLOCK:
+            return seq_len
+        b = min(target, seq_len)
+        while seq_len % b:
+            b -= MIN_BLOCK
+        return b
+    return fit(BLOCKS[0]), fit(BLOCKS[1])
+
+
+def _span(i, length, *, causal: bool, window: int, block_q: int,
+          block_k: int):
+    """(first, last, live): the K/V blocks query block ``i`` of a row of
+    ``length`` attends to, and whether any of its queries is live."""
+    q_start = i * block_q
+    last = jnp.maximum(length - 1, 0) // block_k
+    if causal:
+        last = jnp.minimum(last, (q_start + block_q - 1) // block_k)
+    first = 0
+    if window > 0:      # the oldest query's oldest key in its window
+        first = jnp.maximum(q_start - window + 1, 0) // block_k
+    return first, last, q_start < length
+
+
+def _flash_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, causal: bool, window: int,
+                  softcap: float, sm_scale: float, block_q: int,
+                  block_k: int):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    length = lengths_ref[b]
+    groups, _, d = q_ref.shape
+    q_start, k_start = i * block_q, j * block_k
+
+    @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_start = qi * block_q
-    k_start = kj * block_k
-
-    # Block-level structure: skip blocks that are fully masked.
-    below_diag = (not causal) or (k_start <= q_start + block_q - 1)
+    first, last, live = _span(i, length, causal=causal, window=window,
+                              block_q=block_q, block_k=block_k)
+    run = live & (j >= first) & (j <= last)
+    # does any (query, key) pair of the block fall outside the mask?
+    edge = k_start + block_k > length
+    if causal:
+        edge |= k_start + block_k - 1 > q_start
     if window > 0:
-        # a kv block is skippable only if its newest key is out of window
-        # for the *oldest* query in the q block
-        in_window = k_start + block_k - 1 > q_start - window
-        run = jnp.logical_and(below_diag, in_window)
-    else:
-        run = below_diag
+        edge |= k_start <= q_start + block_q - 1 - window
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)             # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)             # (bk, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+    def step(masked: bool):
+        q = q_ref[...].reshape(groups * block_q, d)
+        v = v_ref[...]
+        s = jax.lax.dot_general(
+            q, k_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         if softcap:
-            logits = softcap * jnp.tanh(logits / softcap)
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask &= k_pos <= q_pos
-        if window > 0:
-            mask &= k_pos > q_pos - window
-        logits = jnp.where(mask, logits, NEG_INF)
-
+            s = softcap * jnp.tanh(s / softcap)
+        if masked:
+            q_pos = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = k_pos < length
+            if causal:
+                mask &= k_pos <= q_pos
+            if window > 0:
+                mask &= k_pos > q_pos - window
+            s = jnp.where(mask[None], s.reshape(groups, block_q, block_k),
+                          NEG_INF).reshape(groups * block_q, block_k)
         m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_cur = jnp.max(logits, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(logits - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None]
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        l_scr[...] = alpha * l_scr[...] + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
-        l_scr[...] = l_new
 
-    @pl.when(kj == nkv - 1)
+    pl.when(run & edge)(functools.partial(step, True))
+    pl.when(run & ~edge)(functools.partial(step, False))
+
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
         l = l_scr[...]
-        denom = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        out = acc_scr[...] / jnp.where(l > 0, l, 1.0)
+        o_ref[...] = out.reshape(groups, block_q, d).astype(o_ref.dtype)
 
 
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    lengths: Optional[jnp.ndarray] = None, *,
                     causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, sm_scale: Optional[float] = None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    softcap: float = 0.0,
                     interpret: bool = False) -> jnp.ndarray:
-    """q: (B, H, Sq, d); k/v: (B, K, Skv, d) → (B, H, Sq, d)."""
-    B, H, Sq, d = q.shape
-    K, Skv = k.shape[1], k.shape[2]
-    assert H % K == 0
-    G = H // K
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Skv)
-    assert Sq % block_q == 0 and Skv % block_k == 0
-    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    """Self-attention of right-padded rows.
 
-    grid = (B, H, Sq // block_q, Skv // block_k)
+    q: (B, H, S, d); k/v: (B, K, S, d); lengths: (B,) live tokens per row
+    (None: all S).  Returns (B, H, S, d): zeros where a whole query block
+    is padding.  Blocks of ``block_sizes(S)``.
+    """
+    B, H, S, d = q.shape
+    K = k.shape[1]
+    assert H % K == 0 and k.shape[2] == S
+    G = H // K
+    block_q, block_k = block_sizes(S)
+    if lengths is None:
+        lengths = jnp.full((B,), S, jnp.int32)
+    span = functools.partial(_span, causal=causal, window=window,
+                             block_q=block_q, block_k=block_k)
+
+    def q_block(b, h, i, j, lens):          # a dead query block keeps the
+        last_q = jnp.maximum(lens[b] - 1, 0) // block_q   # last live one's
+        return b, h, 0, jnp.where(span(i, lens[b])[2], i, last_q), 0
+
+    def kv_block(b, h, i, j, lens):         # a skipped block keeps the index
+        first, last, live = span(i, lens[b])  # of the one before it
+        row_last = jnp.maximum(lens[b] - 1, 0) // block_k
+        return b, h, jnp.where(live, jnp.clip(j, first, last), row_last), 0
+
+    sq = pl.Squeezed()
     kernel = functools.partial(
         _flash_kernel, causal=causal, window=window, softcap=softcap,
-        sm_scale=sm_scale, block_q=block_q, block_k=block_k, kv_len=Skv)
-    return pl.pallas_call(
+        sm_scale=1.0 / math.sqrt(d), block_q=block_q, block_k=block_k)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K, S // block_q, S // block_k),
+            in_specs=[
+                pl.BlockSpec((sq, sq, G, block_q, d), q_block),
+                pl.BlockSpec((sq, sq, block_k, d), kv_block),
+                pl.BlockSpec((sq, sq, block_k, d), kv_block),
+            ],
+            out_specs=pl.BlockSpec((sq, sq, G, block_q, d),
+                                   lambda b, h, i, j, lens: (b, h, 0, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G * block_q, 1), jnp.float32),
+                pltpu.VMEM((G * block_q, 1), jnp.float32),
+                pltpu.VMEM((G * block_q, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, S, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention",
+    )(lengths.astype(jnp.int32), q.reshape(B, K, G, S, d), k, v)
+    return out.reshape(B, H, S, d)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import int8_matmul as _i8
+from repro.kernels import ref as _ref
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import wkv6 as _wkv
 
@@ -25,15 +26,36 @@ def interpret_mode() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
-                                             "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0,
-                    block_q: int = _fa.DEFAULT_BLOCK_Q,
-                    block_k: int = _fa.DEFAULT_BLOCK_K) -> jnp.ndarray:
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap, block_q=block_q,
-                               block_k=block_k, interpret=interpret_mode())
+@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap"))
+def flash_attention(q, k, v, lengths=None, *, causal: bool = True,
+                    window: int = 0, softcap: float = 0.0) -> jnp.ndarray:
+    """Self-attention of right-padded rows, block sizes from the shape.
+    Differentiable: the backward pass is the plain reference's."""
+    if lengths is None:
+        lengths = jnp.full((q.shape[0],), q.shape[2], jnp.int32)
+    return _flash(q, k, v, lengths, causal, window, softcap)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, lengths, causal, window, softcap):
+    return _fa.flash_attention(q, k, v, lengths, causal=causal, window=window,
+                               softcap=softcap, interpret=interpret_mode())
+
+
+def _flash_fwd(q, k, v, lengths, causal, window, softcap):
+    return (_flash(q, k, v, lengths, causal, window, softcap),
+            (q, k, v, lengths))
+
+
+def _flash_bwd(causal, window, softcap, res, g):
+    q, k, v, lengths = res
+    _, vjp = jax.vjp(functools.partial(
+        _ref.mha_reference, causal=causal, window=window, softcap=softcap,
+        lengths=lengths), q, k, v)
+    return (*vjp(g), None)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "block_k"))

@@ -118,7 +118,6 @@ class EncDecLM:
         x = self._embed(params, tokens)
         B, S, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        valid = (positions < lengths[:, None]) if lengths is not None else None
 
         def body(x, p):
             p = L.cast_layer_params(p, cfg.activation_dtype)
@@ -126,7 +125,7 @@ class EncDecLM:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             h = L.attention_apply(p["attn"], h, positions,
                                   rope_theta=cfg.rope_theta, causal=True,
-                                  k_valid=valid)
+                                  lengths=lengths)
             x = x + h
             h = L.rmsnorm(p["lnx"], x, cfg.norm_eps)
             kv = L.cross_attention_kv(p["xattn"], enc_out)
@@ -193,7 +192,7 @@ class EncDecLM:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             y, (k, v) = L.attention_apply(
                 p["attn"], h, positions, rope_theta=cfg.rope_theta, causal=True,
-                k_valid=valid, return_kv=True)
+                lengths=lengths, return_kv=True)
             x = x + y
             W = lc["k"].shape[1]
             kc = lc["k"].at[:, :S].set(k.astype(lc["k"].dtype))

@@ -1,10 +1,10 @@
 """Core model building blocks, written functionally (init fn + apply fn).
 
-Every block here is pure JAX; the Pallas kernels in ``repro.kernels`` are
-numerically-equivalent accelerated paths the engine can switch in (see
-``repro.kernels.ops``).  Parameter pytrees are plain nested dicts; each init
-also has a ``*_axes`` twin returning the logical sharding axes of each leaf
-(consumed by ``repro.dist.sharding``).
+Every block here is pure JAX, except that self-attention takes the flash
+kernel of ``repro.kernels`` where the kernels are compiled for a chip and
+the shapes allow (``_flash_ok``).  Parameter pytrees are plain nested
+dicts; each init also has a ``*_axes`` twin returning the logical sharding
+axes of each leaf (consumed by ``repro.dist.sharding``).
 """
 from __future__ import annotations
 
@@ -14,7 +14,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import constrain_attn_q
+from repro.dist.sharding import constrain_act, constrain_attn_q
+from repro.kernels import flash_attention as fa
+from repro.kernels import ops as kops
+# bound here, so a CPU test can take the kernel path (``_flash_ok``) while
+# the kernel itself still runs interpreted
+from repro.kernels.ops import interpret_mode
 
 Params = Dict[str, Any]
 
@@ -71,16 +76,21 @@ def rmsnorm(params: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 def rope(x: jnp.ndarray, positions: jnp.ndarray,
          theta: Optional[float]) -> jnp.ndarray:
-    """x: (..., S, H, hd) ; positions: broadcastable to (..., S).  A
-    ``theta`` of None is NoPE: no rotation."""
+    """x: (..., S, H, hd) ; positions: broadcastable to (..., S).  Head-major
+    x, (B, H, S, hd), takes positions (B, 1, S): as many axes as x has
+    before hd.  A ``theta`` of None is NoPE: no rotation."""
     if theta is None:
         return x
     hd = x.shape[-1]
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[..., None].astype(jnp.float32) * freqs       # (..., S, half)
-    cos = jnp.cos(angles)[..., None, :]                              # (..., S, 1, half)
-    sin = jnp.sin(angles)[..., None, :]
+    # (..., S, 1, half) over the heads after S; head-major x's positions
+    # already have the heads' axis
+    heads = (lambda a: a[..., None, :]) if angles.ndim < x.ndim else (
+        lambda a: a)
+    cos = heads(jnp.cos(angles))
+    sin = heads(jnp.sin(angles))
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -193,22 +203,16 @@ def attend_cached(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-# Attention implementation toggle: "xla" (pure jnp, default — what the
-# dry-run lowers) or "pallas" (the flash kernel from repro.kernels; used on
-# TPU, validated in interpret mode on CPU).  The kernel path is only legal
-# for dense self-attention with static windows and no ragged k_valid mask.
-_ATTENTION_IMPL = ["xla"]
-
-
-def set_attention_impl(impl: str) -> None:
-    assert impl in ("xla", "pallas")
-    _ATTENTION_IMPL[0] = impl
-
-
-def _flash_ok(positions, window, softcap, k_valid) -> bool:
-    return (_ATTENTION_IMPL[0] == "pallas"
-            and k_valid is None
-            and isinstance(window, int))
+def _flash_ok(x: jnp.ndarray, window, kv) -> bool:
+    """Whether self-attention over x (B, S, D) takes the flash kernel: the
+    kernels are compiled for a chip (not interpreted on the CPU), x is not
+    spread over several devices (the kernel is one device's code, with no
+    rule to partition it), keys and values come from x itself, the window
+    is a Python int (not a per-layer scalar a scan slices), and the
+    kernel's blocks tile the sequence."""
+    return (not interpret_mode() and jax.typeof(x).sharding.mesh.size <= 1
+            and kv is None and isinstance(window, int)
+            and x.shape[1] % fa.MIN_BLOCK == 0)
 
 
 def attention_apply(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
@@ -216,13 +220,20 @@ def attention_apply(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
                     window: int = 0, softcap: float = 0.0,
                     kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                     kv_pos: Optional[jnp.ndarray] = None,
-                    k_valid: Optional[jnp.ndarray] = None,
+                    lengths: Optional[jnp.ndarray] = None,
                     return_kv: bool = False):
     """Full attention block: projections + RoPE + attend + output proj.
 
     When ``kv`` is given it is used as the key/value source (decode against a
-    cache, or cross-attention); otherwise self-attention over ``x``.
+    cache, or cross-attention); otherwise self-attention over ``x``, whose
+    positions are 0..S-1.  ``lengths`` (B,): each row's keys at or past it
+    are padding (None: none are).  Keys and values are returned (B, S, K,
+    hd).  Self-attention takes the flash kernel where ``_flash_ok`` says so.
     """
+    if _flash_ok(x, window, kv):
+        return _flash_attention_apply(params, x, positions, rope_theta,
+                                      causal, window, softcap, lengths,
+                                      return_kv)
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
         if kv is None:
@@ -235,21 +246,41 @@ def attention_apply(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
             kv_pos_eff = kv_pos
         q = rope(q, positions, rope_theta)
         q = constrain_attn_q(q)
+    k_valid = (None if lengths is None
+               else jnp.arange(k.shape[1]) < lengths[:, None])
     with jax.named_scope("attn_core"):
-        if kv is None and _flash_ok(positions, window, softcap, k_valid):
-            from repro.kernels import ops as kops
-            w_eff = 0 if (window or 0) >= (1 << 29) else int(window or 0)
-            out = kops.flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=causal, window=w_eff,
-                softcap=float(softcap)).transpose(0, 2, 1, 3)
-        else:
-            out = attend(q, k, v, positions, kv_pos_eff, causal=causal,
-                         window=window, softcap=softcap, k_valid=k_valid)
+        out = attend(q, k, v, positions, kv_pos_eff, causal=causal,
+                     window=window, softcap=softcap, k_valid=k_valid)
     with jax.named_scope("attn_out"):
         y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     if return_kv:
         return y, (k, v)
+    return y
+
+
+def _flash_attention_apply(params, x, positions, rope_theta, causal, window,
+                           softcap, lengths, return_kv):
+    """``attention_apply`` through the flash kernel: q, k and v come
+    head-major out of their projections, so no transpose pass surrounds
+    the kernel."""
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bsd,dhk->bhsk", x, params["wq"])
+        k = jnp.einsum("bsd,dhk->bhsk", x, params["wk"])
+        v = jnp.einsum("bsd,dhk->bhsk", x, params["wv"])
+        pos = positions[:, None]                       # (B, 1, S)
+        q = constrain_act(rope(q, pos, rope_theta),
+                          "batch", "heads", "seq", "head_dim")
+        k = rope(k, pos, rope_theta)
+    with jax.named_scope("attn_core"):
+        # a window as wide as any sequence is none
+        out = kops.flash_attention(
+            q, k, v, lengths, causal=causal,
+            window=0 if window >= (1 << 29) else window,
+            softcap=float(softcap))
+    with jax.named_scope("attn_out"):
+        y = jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
+    if return_kv:
+        return y, (k.swapaxes(1, 2), v.swapaxes(1, 2))
     return y
 
 

@@ -49,6 +49,11 @@ def _stack_axes(axes_tree):
                         axes_tree, is_leaf=lambda x: isinstance(x, tuple))
 
 
+def _live(lengths, seq_len: int):
+    """(B, S) bool: the live steps of right-padded rows (None: no padding)."""
+    return None if lengths is None else jnp.arange(seq_len) < lengths[:, None]
+
+
 def _gather_last(logits: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
     """logits: (B, S, V) → (B, V) at position lengths-1."""
     b = jnp.arange(logits.shape[0])
@@ -192,24 +197,24 @@ class DecoderLM:
         return logits
 
     # -------------------------------------------------------- full-seq blocks
-    def _attn_block(self, p, x, positions, window, valid):
+    def _attn_block(self, p, x, positions, window, lengths):
         cfg = self.cfg
         with jax.named_scope("attn_qkv"):
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         h = L.attention_apply(
             p["attn"], h, positions, rope_theta=self.rope_theta, causal=True,
-            window=window, softcap=cfg.attn_logit_softcap,
-            k_valid=valid)
+            window=window, softcap=cfg.attn_logit_softcap, lengths=lengths)
         with jax.named_scope("attn_out"):
             return x + h
 
-    def _ssm_block(self, p, x, state, valid):
+    def _ssm_block(self, p, x, state, lengths):
         """``x += Mamba2(RMSNorm(x))`` over a sequence → (x, new state)."""
         cfg = self.cfg
         with jax.named_scope("ssm_in"):
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         h, state = mamba_lib.mixer_seq(p["ssm"], h, state, cfg.mamba_groups,
-                                       cfg.mamba_chunk, valid, cfg.norm_eps)
+                                       cfg.mamba_chunk, _live(lengths, x.shape[1]),
+                                       cfg.norm_eps)
         with jax.named_scope("ssm_out"):
             return x + h, state
 
@@ -225,13 +230,13 @@ class DecoderLM:
                 return x + h, aux
             return x + L.mlp_apply(p["ffn"], h), jnp.float32(0.0)
 
-    def _layer_seq(self, kind, p, x, positions, window, valid, rec_state):
+    def _layer_seq(self, kind, p, x, positions, window, lengths, rec_state):
         """One layer over a full sequence. Returns (x, aux, new_rec_state)."""
         cfg = self.cfg
         p = L.cast_layer_params(p, cfg.activation_dtype)
         x = constrain_act(x, "batch", "seq", "act_embed")
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-            x = self._attn_block(p, x, positions, window, valid)
+            x = self._attn_block(p, x, positions, window, lengths)
             x, aux = self._ffn_block(p, x)
             return x, aux, rec_state
         if kind == RGLRU:
@@ -242,7 +247,7 @@ class DecoderLM:
             x, aux = self._ffn_block(p, x)
             return x, aux, new_state
         if kind == MAMBA2:
-            x, new_state = self._ssm_block(p, x, rec_state, valid)
+            x, new_state = self._ssm_block(p, x, rec_state, lengths)
             x, aux = self._ffn_block(p, x)
             return x, aux, new_state
         if kind == RWKV6:
@@ -258,6 +263,18 @@ class DecoderLM:
             return x + h2, jnp.float32(0.0), {"tm": tm_state, "cm": cm_state}
         raise ValueError(kind)
 
+    def _scan_windows(self):
+        """The attention windows of a homogeneous layer scan: (w, None)
+        where every layer has the same window w, a Python int (the prefill
+        can then take the flash kernel); else (None, an int32 array the
+        scan slices per layer)."""
+        local = self.cfg.local_window
+        windows = [local if k == ATTN_LOCAL else GLOBAL_WINDOW
+                   for k in self.kinds]
+        if len(set(windows)) == 1:
+            return windows[0], None
+        return None, jnp.asarray(windows, dtype=jnp.int32)
+
     # --------------------------------------------------------------- forward
     def forward(self, params, tokens, *, prefix_embeds=None, lengths=None,
                 remat: bool = False,
@@ -272,13 +289,9 @@ class DecoderLM:
         x = self._embed(params, tokens, prefix_embeds)
         B, S, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        valid = (positions < lengths[:, None]) if lengths is not None else None
 
         if self.homogeneous:
             kind0 = self.kinds[0]
-            windows = jnp.asarray(
-                [cfg.local_window if k == ATTN_LOCAL else GLOBAL_WINDOW
-                 for k in self.kinds], dtype=jnp.int32)
             if kind0 == RWKV6:
                 states = jax.vmap(
                     lambda _: rwkv_lib.init_state(B, cfg.d_model,
@@ -289,19 +302,21 @@ class DecoderLM:
                     x, aux = carry
                     p, st = xs
                     x, a, _ = self._layer_seq(RWKV6, p, x, positions,
-                                              GLOBAL_WINDOW, valid, st)
+                                              GLOBAL_WINDOW, lengths, st)
                     return (x, aux + a), None
                 body = jax.checkpoint(body) if remat else body
                 (x, aux), _ = scan_layers(body, (x, jnp.float32(0.0)),
                                           (params["layers"], states),
                                           cfg.cost_unroll)
             else:
+                window, windows = self._scan_windows()
                 def body(carry, xs):
                     x, aux = carry
                     p, w = xs
                     p = L.cast_layer_params(p, cfg.activation_dtype)
                     x = constrain_act(x, "batch", "seq", "act_embed")
-                    x = self._attn_block(p, x, positions, w, valid)
+                    x = self._attn_block(p, x, positions,
+                                         window if w is None else w, lengths)
                     x, a = self._ffn_block(p, x)
                     return (x, aux + a), None
                 body = jax.checkpoint(body) if remat else body
@@ -309,7 +324,8 @@ class DecoderLM:
                                           (params["layers"], windows),
                                           cfg.cost_unroll)
         else:
-            x, aux = self._forward_hybrid(params, x, positions, valid, remat)
+            x, aux = self._forward_hybrid(params, x, positions, lengths,
+                                          remat)
 
         with jax.named_scope("logits"):
             x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
@@ -317,7 +333,7 @@ class DecoderLM:
                 return x, aux
             return self._logits(params, x), aux
 
-    def _forward_hybrid(self, params, x, positions, valid, remat):
+    def _forward_hybrid(self, params, x, positions, lengths, remat):
         cfg = self.cfg
         B = x.shape[0]
         def fresh_state(kind):
@@ -329,7 +345,7 @@ class DecoderLM:
             for i, kind in enumerate(cfg.layer_pattern):
                 w = cfg.local_window if kind == ATTN_LOCAL else GLOBAL_WINDOW
                 x, a, _ = self._layer_seq(kind, p[f"l{i}"], x, positions, w,
-                                          valid, fresh_state(kind))
+                                          lengths, fresh_state(kind))
                 aux = aux + a
             return (x, aux), None
         body = jax.checkpoint(period_body) if remat else period_body
@@ -338,7 +354,8 @@ class DecoderLM:
         for i, kind in enumerate(self.tail_kinds):
             w = cfg.local_window if kind == ATTN_LOCAL else GLOBAL_WINDOW
             x, a, _ = self._layer_seq(kind, params["tail"][f"t{i}"], x,
-                                      positions, w, valid, fresh_state(kind))
+                                      positions, w, lengths,
+                                      fresh_state(kind))
             aux = aux + a
         return x, aux
 
@@ -424,26 +441,27 @@ class DecoderLM:
         return axes
 
     # --------------------------------------------------- cached attention ops
-    def _attn_prefill(self, p, x, positions, window, valid, lc):
+    def _attn_prefill(self, p, x, positions, window, lengths, lc):
         """Self-attn over the prompt, writing into an (unrotated) cache."""
         cfg = self.cfg
         with jax.named_scope("attn_qkv"):
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         y, (k, v) = L.attention_apply(
             p["attn"], h, positions, rope_theta=self.rope_theta, causal=True,
-            window=window, softcap=cfg.attn_logit_softcap, k_valid=valid,
+            window=window, softcap=cfg.attn_logit_softcap, lengths=lengths,
             return_kv=True)
         with jax.named_scope("kv_write"):
-            lc = self._write_prompt_kv(k, v, positions, valid, lc)
+            lc = self._write_prompt_kv(k, v, positions, lengths, lc)
         with jax.named_scope("attn_out"):
             return x + y, lc
 
     @staticmethod
-    def _write_prompt_kv(k, v, positions, valid, lc):
+    def _write_prompt_kv(k, v, positions, lengths, lc):
         """The prompt's keys and values, (B, S, K, hd), into the layer's
         head-major cache, (B, K, W, hd)."""
         W = lc["k"].shape[2]
         B, S = positions.shape
+        valid = _live(lengths, S)
         head_major = lambda a: a.transpose(0, 2, 1, 3).astype(lc["k"].dtype)
         if W >= S:
             kc = lc["k"].at[:, :, :S].set(head_major(k))
@@ -552,26 +570,29 @@ class DecoderLM:
         return x, periods
 
     # ---------------------------------------------------------------- prefill
-    def _layer_prefill(self, kind, p, x, positions, window, valid, lc):
+    def _layer_prefill(self, kind, p, x, positions, window, lengths, lc):
         cfg = self.cfg
         p = L.cast_layer_params(p, cfg.activation_dtype)
         x = constrain_act(x, "batch", "seq", "act_embed")
         if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-            x, lc = self._attn_prefill(p, x, positions, window, valid, lc)
+            x, lc = self._attn_prefill(p, x, positions, window, lengths,
+                                       lc)
             x, _ = self._ffn_block(p, x)
             return x, lc
         if kind == RGLRU:
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             with jax.named_scope("rglru"):
-                h, lc = rglru_lib.rglru_block_seq(p["rec"], h, lc, valid=valid)
+                h, lc = rglru_lib.rglru_block_seq(
+                    p["rec"], h, lc, valid=_live(lengths, x.shape[1]))
             x = x + h
             x, _ = self._ffn_block(p, x)
             return x, lc
         if kind == MAMBA2:
-            x, lc = self._ssm_block(p, x, lc, valid)
+            x, lc = self._ssm_block(p, x, lc, lengths)
             x, _ = self._ffn_block(p, x)
             return x, lc
         if kind == RWKV6:
+            valid = _live(lengths, x.shape[1])
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             with jax.named_scope("rwkv_time_mix"):
                 h, tm = rwkv_lib.time_mix_seq(p["tm_cm"], h, cfg.rwkv_head_dim,
@@ -591,18 +612,15 @@ class DecoderLM:
         x = self._embed(params, tokens, prefix_embeds)
         B, S, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        valid = positions < lengths[:, None]
 
         if self.homogeneous:
-            windows = jnp.asarray(
-                [cfg.local_window if k == ATTN_LOCAL else GLOBAL_WINDOW
-                 for k in self.kinds], dtype=jnp.int32)
+            window, windows = self._scan_windows()
             kind0 = RWKV6 if self.kinds[0] == RWKV6 else ATTN_GLOBAL
             def body(x, xs):
                 p, w, lc = xs
                 x, lc = self._layer_prefill(
                     self.kinds[0] if kind0 == RWKV6 else ATTN_GLOBAL,
-                    p, x, positions, w, valid, lc)
+                    p, x, positions, window if w is None else w, lengths, lc)
                 return x, lc
             x, new_layers = scan_layers(body, x,
                                         (params["layers"], windows,
@@ -610,14 +628,15 @@ class DecoderLM:
             new_cache = {"lengths": lengths, "layers": new_layers}
         else:
             def step(n, kind, p, x, w, lc):
-                return self._layer_prefill(kind, p, x, positions, w, valid, lc)
+                return self._layer_prefill(kind, p, x, positions, w, lengths,
+                                           lc)
             x, new_periods = self._over_periods(params, cache["periods"], x,
                                                 step)
             new_tail = {}
             for i, kind in enumerate(self.tail_kinds):
                 w = cfg.local_window if kind == ATTN_LOCAL else GLOBAL_WINDOW
                 x, new_tail[f"t{i}"] = self._layer_prefill(
-                    kind, params["tail"][f"t{i}"], x, positions, w, valid,
+                    kind, params["tail"][f"t{i}"], x, positions, w, lengths,
                     cache["tail"][f"t{i}"])
             new_cache = {"lengths": lengths, "periods": new_periods,
                          "tail": new_tail}

@@ -1,43 +1,79 @@
-"""The Pallas flash-attention path must agree with the XLA path at the
-model level (full forward of a dense and a local-window arch)."""
+"""Which attention path a layer takes, and that both give one answer.
+
+Self-attention takes the flash kernel where the kernels are compiled for a
+chip and the shapes allow; on the CPU the suite forces that path by
+patching the backend check, and the kernel runs interpreted."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS
+from repro.kernels import ops as kops
 from repro.models import build_model, reduced
 from repro.models import layers as L
+from repro.models.transformer import GLOBAL_WINDOW
 
 
-@pytest.mark.parametrize("name", ["granite-3-2b", "gemma2-2b"])
-def test_flash_path_matches_xla(name):
+def _prefill(model, params, tokens, lengths):
+    cache = model.init_cache(tokens.shape[0], tokens.shape[1] + 8)
+    return model.prefill(params, cache, tokens, lengths)
+
+
+@pytest.mark.parametrize("name", ["granite-3-2b", "granite-4.0-h-micro"])
+def test_flash_path_matches_xla(name, monkeypatch):
+    """The prefill through the kernel (forced) equals the one through
+    ``attend``: the last position's logits and every live cache slot."""
     cfg = reduced(ARCHS[name])
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
-    tokens = jax.random.randint(jax.random.key(1), (2, 64), 0, cfg.vocab_size)
-    try:
-        L.set_attention_impl("xla")
-        ref, _ = model.forward(params, tokens)
-        L.set_attention_impl("pallas")
-        out, _ = model.forward(params, tokens)
-    finally:
-        L.set_attention_impl("xla")
+    tokens = jax.random.randint(jax.random.key(1), (3, 256), 0,
+                                cfg.vocab_size)
+    lengths = jnp.asarray([256, 130, 77], jnp.int32)
+    ref_cache, ref = _prefill(model, params, tokens, lengths)
+
+    calls = []
+    kernel = kops.flash_attention
+    monkeypatch.setattr(L, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kops, "flash_attention",
+                        lambda *a, **kw: calls.append(1) or kernel(*a, **kw))
+    cache, out = _prefill(model, params, tokens, lengths)
+    assert calls, "the kernel path was not taken"
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=5e-3, rtol=5e-3)
+                               atol=1e-4, rtol=1e-4)
+    live = np.arange(tokens.shape[1] + 8) < np.asarray(lengths)[:, None]
+    slots = 0
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(cache),
+                                 jax.tree.leaves(ref_cache)):
+        if path[-1].key not in ("k", "v"):
+            continue
+        # (..., B, K, W, hd): compare each row's live slots
+        got = np.moveaxis(np.asarray(got), -3, -2)[..., live, :, :]
+        want = np.moveaxis(np.asarray(want), -3, -2)[..., live, :, :]
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+        slots += 1
+    assert slots >= 2
 
 
-def test_ragged_masks_fall_back_to_xla():
-    """prefill (k_valid mask) must not take the kernel path."""
-    try:
-        L.set_attention_impl("pallas")
-        assert not L._flash_ok(None, 0, 0.0, jnp.ones((2, 8), bool))
-        assert L._flash_ok(None, 0, 0.0, None)
-        # traced per-layer window scalars are not static ints -> fallback
-        assert not L._flash_ok(None, jnp.int32(4), 0.0, None)
-    finally:
-        L.set_attention_impl("xla")
-    assert not L._flash_ok(None, 0, 0.0, None)   # toggle off -> xla
+@pytest.mark.parametrize("chip,seq_len,window,kv,takes", [
+    (True, 256, 0, None, True),
+    (True, 2048, GLOBAL_WINDOW, None, True),
+    (True, 768, 64, None, True),
+    (False, 256, 0, None, False),
+    (True, 256, jnp.int32(64), None, False),
+    (True, 200, 0, None, False),
+    (True, 256, 0, "cache", False),
+], ids=["chip", "global", "static_local", "cpu", "traced_window",
+        "unaligned_seq", "kv_given"])
+def test_ragged_masks_fall_back_to_xla(chip, seq_len, window, kv, takes,
+                                       monkeypatch):
+    """The kernel is taken on a chip, for self-attention with a static
+    window over a sequence its blocks tile, right-padded rows included;
+    every other case falls back to ``attend``.  (A program over several
+    devices: ``test_tpu_compile.py``.)"""
+    monkeypatch.setattr(L, "interpret_mode", lambda: not chip)
+    x = jnp.zeros((1, seq_len, 8), jnp.bfloat16)
+    assert L._flash_ok(x, window, kv) is takes
 
 
 @pytest.mark.parametrize("softcap,window", [

@@ -23,24 +23,50 @@ def rand(k, shape, dtype=jnp.float32, scale=1.0):
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
-@pytest.mark.parametrize("B,H,K,S,d", [
-    (1, 4, 4, 128, 64), (2, 8, 4, 256, 64), (1, 8, 2, 256, 128),
-    (2, 4, 1, 128, 256),
+@pytest.mark.parametrize("B,H,K,S,d,lengths", [
+    (1, 4, 4, 128, 64, None), (2, 8, 4, 256, 64, None),
+    (1, 8, 2, 256, 128, None), (2, 4, 1, 128, 256, None),
+    # right-padded rows, groups of 4: one at full length, one that ends
+    # mid-block, one whose last query blocks are all padding
+    (3, 8, 2, 1024, 64, (1024, 700, 200)),
+    (3, 8, 2, 1024, 128, (1024, 300, 130)),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("opts", [
     dict(causal=True), dict(causal=True, window=64),
     dict(causal=True, softcap=30.0), dict(causal=False),
 ])
-def test_flash_attention(B, H, K, S, d, dtype, opts):
+def test_flash_attention(B, H, K, S, d, lengths, dtype, opts):
     q = rand(1, (B, H, S, d), dtype)
     k = rand(2, (B, K, S, d), dtype)
     v = rand(3, (B, K, S, d), dtype)
-    out = flash_attention(q, k, v, interpret=True, **opts)
-    want = ref.mha_reference(q, k, v, **opts)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
+    out = np.asarray(flash_attention(q, k, v, lengths, interpret=True,
+                                     **opts), np.float32)
+    want = np.asarray(ref.mha_reference(q, k, v, lengths=lengths, **opts),
+                      np.float32)
+    assert np.isfinite(out).all()
+    live = np.arange(S) < (S if lengths is None
+                           else np.asarray(lengths)[:, None, None])
+    live = np.broadcast_to(live, (B, H, S))
+    np.testing.assert_allclose(out[live], want[live],
                                atol=TOL[dtype], rtol=TOL[dtype] * 10)
+
+
+def test_flash_attention_gradient_is_the_references():
+    """The served entry differentiates through the plain reference."""
+    from repro.kernels import ops
+    q, k, v = rand(1, (2, 4, 128, 32)), rand(2, (2, 2, 128, 32)), rand(
+        3, (2, 2, 128, 32))
+    lengths = jnp.asarray([128, 50], jnp.int32)
+    loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+    got = jax.grad(loss(lambda *a: ops.flash_attention(*a, lengths)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: ref.mha_reference(*a, lengths=lengths)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("B,H,K,T,d", [
